@@ -58,6 +58,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import hashlib
+import re
 import time
 from typing import Callable, NamedTuple, Optional
 
@@ -134,19 +135,25 @@ def _fault(site: str, **info):
 # --------------------------------------------------------------------------
 # Allocation-failure detection and the tier ladder.
 
-_ALLOC_TOKENS = ("resource_exhausted", "out of memory", "failed to allocate",
-                 "oom")
+#: Whole-word markers of an allocation failure in an error message.
+_ALLOC_WORDS = re.compile(
+    r"\b(resource_exhausted|out of memory|failed to allocate|oom)\b")
+#: A kernel the TPU compiler refuses — over its scoped-VMEM budget included —
+#: is a bug in that kernel's blocking, which no other tier fixes.
+_COMPILE_REFUSAL = re.compile(r"\bmosaic\b")
 
 
 def is_allocation_failure(exc: BaseException) -> bool:
     """Whether ``exc`` looks like a memory-allocation failure (XLA
     RESOURCE_EXHAUSTED, allocator OOM, host ``MemoryError``) — the class of
     error the tier ladder can actually fix, as opposed to bugs it must
-    propagate."""
+    propagate. Markers match whole words only ("oom" is not "room"), and a
+    Mosaic compile refusal never counts, whatever memory it names."""
     if isinstance(exc, MemoryError):
         return True
     msg = str(exc).lower()
-    return any(tok in msg for tok in _ALLOC_TOKENS)
+    return (_ALLOC_WORDS.search(msg) is not None
+            and _COMPILE_REFUSAL.search(msg) is None)
 
 
 def next_tier(fmt: str, problem: ising.IsingProblem, mesh) -> Optional[str]:

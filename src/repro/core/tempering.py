@@ -161,7 +161,7 @@ def fused_tempering_round(state, acc, tot, base: jax.Array, round_idx,
     state = _ops.fused_sweep_chunk(
         store.kernel_operand, state, rng.stream(base, rng.Salt.SWEEP, round_idx),
         config.swap_every, temps_trs, mode=config.mode, pwl_table=tbl,
-        block_r=_ops.fit_block(r, 8), coupling=store.fmt, interpret=interpret)
+        coupling=store.fmt, interpret=interpret)
     state, (a, t) = _swap_phase(state, lambda st: st[2], temps,
                                 base, round_idx, r)
     return state, acc + a, tot + t

@@ -1,28 +1,15 @@
-"""shard_map across JAX versions.
-
-Newer JAX exposes ``jax.shard_map`` (with ``check_vma``); older releases ship
-``jax.experimental.shard_map.shard_map`` (with ``check_rep``). Every
-shard_map call in this repo goes through :func:`shard_map_compat` so the
-distributed drivers and their tests run on both.
-"""
+"""shard_map as every distributed driver in this repo calls it."""
 from __future__ import annotations
 
 import jax
 
 
 def axis_size(axis: str):
-    """Size of a mapped mesh axis — ``lax.axis_size`` on new JAX, the
-    ``psum(1, axis)`` idiom (constant-folded) on old releases."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+    """Size of a mapped mesh axis."""
+    return jax.lax.axis_size(axis)
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, on any supported JAX."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -96,7 +96,6 @@ def _fused_chunk_runner(base_cfg: SolverConfig, chunk_steps: int, r_local: int,
     from ..kernels import ops as _ops
 
     tbl = _ops.solver_pwl_table(base_cfg)
-    block_r = _ops.fit_block(r_local, 8)
 
     def run(states, base, device_idx, chunk_idx, dense_J=None):
         # Plane stores close over the encoded payload (replicated constant);
@@ -114,7 +113,7 @@ def _fused_chunk_runner(base_cfg: SolverConfig, chunk_steps: int, r_local: int,
             rng.stream(base, rng.Salt.SWEEP, device_idx, chunk_idx),
             chunk_steps, temps, mode=base_cfg.mode,
             uniformized=base_cfg.uniformized, pwl_table=tbl,
-            block_r=block_r, coupling=store.fmt, interpret=interpret)
+            coupling=store.fmt, interpret=interpret)
         return mcmc.ChainState(
             spins=s.astype(ising.SPIN_DTYPE),
             fields=u,

@@ -186,18 +186,14 @@ def _sharded_roulette(p_loc, u_roulette, lane, g0, axes):
     arithmetic of the single-device pick on identical values — the exactness
     argument of the four-way parity tier.
     """
-    r_, n_loc = p_loc.shape
-    g_loc = n_loc // lane
-    pb = p_loc.reshape(r_, g_loc, lane)
-    blk_loc = jnp.sum(pb, axis=2)                         # (R, G_loc)
+    blk_loc = common.block_sums(p_loc, lane)             # (R, G_loc)
     blk = jax.lax.all_gather(blk_loc, axes, axis=1, tiled=True)  # (R, G)
-    g, residual, total, degenerate = common.roulette_block_pick(blk, u_roulette)
-    iota_loc = g0 + jax.lax.broadcasted_iota(jnp.int32, (r_, g_loc), 1)
-    sel_loc = jnp.sum(jnp.where((iota_loc == g[:, None])[:, :, None], pb, 0.0),
-                      axis=1)                             # (R, lane) masked
-    sel = jax.lax.psum(sel_loc, axes)
+    g, residual, total, degenerate = common.roulette_block_pick(
+        blk, u_roulette[:, None])
+    sel = jax.lax.psum(common.select_block(p_loc, g, lane, g0), axes)
     l = common.roulette_lane_pick(sel, residual, lane)
-    return (g * lane + l).astype(jnp.int32), total, degenerate
+    return ((g * lane + l)[:, 0].astype(jnp.int32), total[:, 0],
+            degenerate[:, 0])
 
 
 def _sharded_sweep(planes_loc: BitPlanes, fields0, spins0, energy0, uniforms,
@@ -273,7 +269,8 @@ def _sharded_sweep(planes_loc: BitPlanes, fields0, spins0, energy0, uniforms,
         the site alone, so the broadcast-back is byte-identical to
         fetch-per-replica and the trajectory cannot move."""
         if coalesce:
-            nu, usite, uo, fetched = common.coalesce_rows(j)
+            nu, usite, uo, fetched = common.coalesce_rows(j[:, None])
+            usite, uo, fetched = usite[:, 0], uo[:, 0], fetched[:, 0]
             jl = jnp.clip(usite - lo, 0, n_loc - 1)
             own = (usite >= lo) & (usite < lo + n_loc)
             zeros = jnp.zeros((2 * num_planes, 1, num_words), jnp.uint32)

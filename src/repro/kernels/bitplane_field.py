@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import common
 
@@ -44,17 +45,22 @@ def bitplane_field_init(pos: jax.Array, neg: jax.Array, spin_words: jax.Array,
                         interpret: bool = False) -> jax.Array:
     """u^(J)[r, i] from packed planes (Eq. 14-16). Returns (R, N) f32.
 
-    ``block_r``/``block_n`` clamp to the largest divisors of R/N ≤ the
-    requested sizes (BlockSpec grids need exact tiling; a non-dividing
-    request falls back instead of erroring).
+    ``block_r`` resolves through ``common.replica_block``; ``block_n`` clamps
+    to N and need not divide it — the edge block computes rows past N, whose
+    results are never stored (each output row depends on its own plane row
+    only). Past N, a legal TPU block is a multiple of 128.
     """
     num_planes, n, w = pos.shape
     assert neg.shape == pos.shape
     r = spin_words.shape[0]
     assert spin_words.shape == (r, w)
-    br = common.fit_block(r, block_r)
-    bn = common.fit_block(n, block_n)
-    grid = (n // bn, r // br)
+    br = common.replica_block(r, block_r)
+    bn = min(block_n, n)
+    grid = (pl.cdiv(n, bn), r // br)
+    plane_blk = common.vmem_bytes((num_planes, bn, w), jnp.uint32)
+    nbytes = 2 * (2 * plane_blk + common.vmem_bytes((br, w), jnp.uint32)
+                  + common.vmem_bytes((br, bn), jnp.float32)) \
+        + 4 * br * common.vmem_bytes((bn, w), jnp.uint32)
     return pl.pallas_call(
         functools.partial(_kernel, num_planes=num_planes),
         grid=grid,
@@ -65,5 +71,9 @@ def bitplane_field_init(pos: jax.Array, neg: jax.Array, spin_words: jax.Array,
         ],
         out_specs=pl.BlockSpec((br, bn), lambda i, j: (j, i)),
         out_shape=jax.ShapeDtypeStruct((r, n), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=common.vmem_limit(nbytes)),
         interpret=interpret,
+        name="bitplane_field_init",
     )(pos, neg, spin_words)

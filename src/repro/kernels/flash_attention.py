@@ -45,10 +45,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool, block_q: int,
 
     def body(kj, carry):
         m, l, acc = carry
-        k_blk = pl.load(k_ref, (0, 0, pl.ds(kj * block_k, block_k), slice(None))
-                        ).astype(jnp.float32)  # (bk, d)
-        v_blk = pl.load(v_ref, (0, 0, pl.ds(kj * block_k, block_k), slice(None))
-                        ).astype(jnp.float32)
+        k_blk = k_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(
+            jnp.float32)  # (bk, d)
+        v_blk = v_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(
+            jnp.float32)
         s = jax.lax.dot_general(q, k_blk, (((2,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (rep,bq,bk)
         if causal:

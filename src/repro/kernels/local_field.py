@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import common
+
 
 def _kernel(s_ref, j_ref, h_ref, out_ref, acc_ref, *, num_k: int):
     k = pl.program_id(2)
@@ -43,16 +45,24 @@ def local_field_init(spins: jax.Array, couplings: jax.Array, bias: jax.Array,
                      *, block_r: int = 8, block_n: int = 256, block_k: int = 512,
                      interpret: bool = False) -> jax.Array:
     """u[r] = J @ s[r] + h for a replica batch. spins (R,N) ±1 (any int/float
-    dtype), couplings (N,N), bias (N,). Returns (R,N) f32."""
+    dtype), couplings (N,N), bias (N,). Returns (R,N) f32. ``block_r`` and
+    ``block_k`` must divide R and N; ``block_n`` need not — the edge block of
+    output columns reads rows past N, whose results are never stored."""
     r, n = spins.shape
     assert couplings.shape == (n, n) and bias.shape == (n,)
     br = min(block_r, r)
     bn = min(block_n, n)
     bk = min(block_k, n)
-    if r % br or n % bn or n % bk:
-        raise ValueError(f"shape ({r},{n}) not divisible by blocks ({br},{bn},{bk})")
+    if r % br or n % bk:
+        raise ValueError(f"shape ({r},{n}) not divisible by blocks ({br},{bk})")
     num_k = n // bk
-    grid = (r // br, n // bn, num_k)
+    grid = (r // br, pl.cdiv(n, bn), num_k)
+    j_blk = common.vmem_bytes((bn, bk), couplings.dtype)
+    out_blk = common.vmem_bytes((br, bn), jnp.float32)
+    # Double-buffered blocks, the accumulator, and the f32 copy of the J tile.
+    nbytes = (2 * (common.vmem_bytes((br, bk), spins.dtype) + j_blk
+                   + common.vmem_bytes((1, bn), bias.dtype) + out_blk)
+              + out_blk + common.vmem_bytes((bn, bk), jnp.float32))
     return pl.pallas_call(
         functools.partial(_kernel, num_k=num_k),
         grid=grid,
@@ -64,5 +74,9 @@ def local_field_init(spins: jax.Array, couplings: jax.Array, bias: jax.Array,
         out_specs=pl.BlockSpec((br, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((r, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((br, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=common.vmem_limit(nbytes)),
         interpret=interpret,
+        name="local_field_init",
     )(spins, couplings, bias.reshape(1, n))

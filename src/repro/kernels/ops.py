@@ -35,7 +35,7 @@ from ..core.solver import SolverConfig, SolveResult
 from . import bitplane_field as _bitplane_field
 from . import local_field as _local_field
 from . import sweep as _sweep
-from .common import fit_block  # noqa: F401  (canonical home is kernels.common)
+from .common import replica_block
 
 #: N at or below which the one-hot MXU row gather beats per-replica dynamic
 #: slices (one small matmul vs br sequential row DMAs) — the opt-in heuristic
@@ -53,9 +53,10 @@ def local_field_init(spins: jax.Array, couplings: jax.Array, bias: jax.Array,
                      *, interpret: Optional[bool] = None, **kw) -> jax.Array:
     """Batched u = J s + h via the MXU matmul kernel."""
     r, n = spins.shape
-    kw.setdefault("block_r", fit_block(r, 8))
-    kw.setdefault("block_n", fit_block(n, 256))
-    kw.setdefault("block_k", fit_block(n, 512))
+    kw.setdefault("block_r", replica_block(r, 8))
+    # Contraction blocks must divide N and be lane multiples; else all of N.
+    kw.setdefault("block_k", next((k for k in (512, 384, 256, 128)
+                                   if n % k == 0), n))
     return _local_field.local_field_init(
         spins, couplings, bias, interpret=auto_interpret(interpret), **kw)
 
@@ -89,10 +90,8 @@ def plane_local_fields(planes: BitPlanes, spins0: jax.Array, *,
     matmul path."""
     if interpret:
         return local_fields_from_planes(planes, spins0)
-    r, n = spins0.shape
     return bitplane_field_init(planes, spins0, interpret=False,
-                               block_r=fit_block(r, block_r),
-                               block_n=fit_block(n, 256))
+                               block_r=block_r)
 
 
 def init_fields(problem: ising.IsingProblem, spins0: jax.Array, *,
@@ -108,7 +107,8 @@ def init_fields(problem: ising.IsingProblem, spins0: jax.Array, *,
         return ising.local_fields(problem, spins0).astype(jnp.float32)
     r = spins0.shape[0]
     return local_field_init(spins0, problem.couplings, problem.fields,
-                            interpret=False, block_r=fit_block(r, block_r))
+                            interpret=False,
+                            block_r=replica_block(r, block_r))
 
 
 def fused_init_state(problem: ising.IsingProblem, base: jax.Array, r: int, *,
@@ -249,7 +249,7 @@ def anneal_chunk_step(store: CouplingStore, state, base: jax.Array,
         store.kernel_operand, state, rng.stream(base, rng.Salt.SWEEP, c),
         clen, temps, mode=config.mode, uniformized=config.uniformized,
         pwl_table=solver_pwl_table(config), gather=gather,
-        block_r=fit_block(r, block_r), coupling=store.fmt,
+        block_r=block_r, coupling=store.fmt,
         with_rows_fetched=with_rows_fetched, interpret=interpret)
 
 
@@ -303,17 +303,20 @@ class ColoredPlan:
     the permuted spin order is ``coloring.perm`` and results map back through
     ``coloring.inverse_perm``.
 
-    Window math: with ``lane = common.default_lane(n)`` the static class
-    window is ``S = min(n, roundup(max_class_size + lane - 1, lane))`` and
-    class c starts its window at ``w_c = min((offsets[c] // lane)·lane,
-    n - S)``. Coverage: ``w_c ≤ offsets[c]`` (floor) and ``w_c + S ≥
-    offsets[c] - (lane-1) + (size_c + lane - 1) = offsets[c] + size_c``, so
-    every class fits its lane-aligned window.
+    Window math: the kernel slices its window at a dynamic lane offset,
+    which must be a multiple of L = ``common.LANE_TILE`` (128), out of the
+    state padded to ``N_pad = roundup(N, L)``. The static class window is
+    ``S = min(N_pad, roundup(max_class_size + L - 1, L))`` and class c
+    starts its window at ``w_c = min((offsets[c] // L)·L, N_pad - S)`` —
+    both multiples of L. Coverage: ``w_c ≤ offsets[c]`` (floor) and
+    ``w_c + S ≥ offsets[c] - (L-1) + (size_c + L - 1) = offsets[c] +
+    size_c`` (or ``= N_pad`` when clamped), so every class fits its
+    lane-aligned window.
     """
 
     def __init__(self, coloring, problem: ising.IsingProblem, fmt,
                  num_planes: Optional[int] = None):
-        from .common import default_lane
+        from .common import LANE_TILE, round_up
 
         n = problem.num_spins
         self.coloring = coloring
@@ -334,13 +337,14 @@ class ColoredPlan:
         self.store = CouplingStore.build(self.problem.coupling_source, fmt,
                                          num_planes=num_planes)
         self.store.require(KERNEL_COUPLING_MODES, "colored_anneal")
-        lane = default_lane(n)
         import numpy as _np
 
-        max_class = coloring.max_class_size
-        self.window = min(n, -(-(max_class + lane - 1) // lane) * lane)
+        lane = LANE_TILE
+        n_pad = round_up(n, lane)
+        self.window = min(n_pad, round_up(coloring.max_class_size + lane - 1,
+                                          lane))
         offs = coloring.offsets[:-1]
-        w = _np.minimum((offs // lane) * lane, n - self.window)
+        w = _np.minimum((offs // lane) * lane, n_pad - self.window)
         self.wstarts = jnp.asarray(w, jnp.int32)
         self.offsets = jnp.asarray(offs, jnp.int32)
         self.sizes = jnp.asarray(coloring.class_sizes, jnp.int32)
@@ -436,7 +440,7 @@ def colored_chunk_step(plan: ColoredPlan, state, base: jax.Array,
         plan.store.kernel_operand, state,
         rng.stream(base, rng.Salt.SWEEP, c), clen, temps, sched,
         window=plan.window, pwl_table=solver_pwl_table(config),
-        block_r=fit_block(r, block_r), coupling=plan.store.fmt,
+        block_r=block_r, coupling=plan.store.fmt,
         with_rows_fetched=with_rows_fetched, interpret=interpret)
 
 

@@ -41,7 +41,8 @@ def colored_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
 
     Same contract: spins in color-sorted order, ``sched`` (T, 3) int32 rows of
     (window_start, class_offset, class_size), ``uniforms`` (T, R, S) accept
-    streams over the static class window S. Per step every member of the
+    streams over the static class window S; windows index the state padded
+    to a lane multiple, as in the kernel. Per step every member of the
     scheduled class takes an independent heat-bath flip off the live local
     fields (exact block Gibbs — same-color spins share no coupling), then the
     accepted subset's rank-1 row updates are applied slot by slot through the
@@ -72,10 +73,13 @@ def colored_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
             return jax.lax.dynamic_slice_in_dim(J, jr, 1, axis=0)
 
     r = fields0.shape[0]
-    br = common.fit_block(r, block_r)
+    br = common.replica_block(r, block_r)
     g = r // br
     win = uniforms.shape[2]
     ids = jnp.arange(br, dtype=jnp.int32)
+    # The kernel's lane-padded state: windows are lane-aligned slices of it.
+    n_pad = common.round_up(n, common.LANE_TILE)
+    pad = ((0, 0), (0, n_pad - n))
 
     def body(carry, xs):
         u, s, e, be, bs, nf, rf = carry
@@ -100,7 +104,7 @@ def colored_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
             s_old_k = jax.lax.dynamic_slice(s_win, (0, k), (r, 1))
             acc_b = acc_k.reshape(g, br)
             anyacc = jnp.sum(acc_b, axis=1) > 0.0                  # (G,)
-            row = fetch_row(w + k)                                 # (1, N)
+            row = jnp.pad(fetch_row(w + k), pad)                   # (1, N_pad)
             gate = jnp.repeat(anyacc, br)[:, None]
             u = jnp.where(gate, u - (2.0 * acc_k * s_old_k) * row, u)
             first = jnp.min(jnp.where(acc_b > 0.0, ids[None, :], br), axis=1)
@@ -114,14 +118,14 @@ def colored_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
         bs = jnp.where(better[:, None], s, bs)
         return (u, s, e, be, bs, nf, rf), None
 
-    init = (fields0.astype(jnp.float32), spins0.astype(jnp.float32),
-            energy0.astype(jnp.float32), energy0.astype(jnp.float32),
-            spins0.astype(jnp.float32), jnp.zeros((r,), jnp.int32),
-            jnp.zeros((r,), jnp.int32))
+    u0 = jnp.pad(fields0.astype(jnp.float32), pad)
+    s0 = jnp.pad(spins0.astype(jnp.float32), pad, constant_values=1.0)
+    init = (u0, s0, energy0.astype(jnp.float32), energy0.astype(jnp.float32),
+            s0, jnp.zeros((r,), jnp.int32), jnp.zeros((r,), jnp.int32))
     (u, s, e, be, bs, nf, rf), _ = jax.lax.scan(
         body, init, (uniforms, temps, sched.astype(jnp.int32)))
-    return (u, s.astype(spins0.dtype), e, be, bs.astype(spins0.dtype),
-            nf, rf)
+    return (u[:, :n], s[:, :n].astype(spins0.dtype), e, be,
+            bs[:, :n].astype(spins0.dtype), nf, rf)
 
 
 def mcmc_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
@@ -164,36 +168,38 @@ def mcmc_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
     def body(carry, xs):
         u, s, e, be, bs, nf = carry
         u01, temp = xs                       # (R, 4), (R,)
+        temp = temp[:, None]
         sf = s.astype(jnp.float32)
+        # Per-replica quantities are (R, 1) columns, as in the kernel.
         if mode == "rsa":
-            j = common.site_from_uniform(u01[:, 0], n)
-            u_j = jnp.take_along_axis(u, j[:, None], axis=1)[:, 0]
-            s_j = jnp.take_along_axis(sf, j[:, None], axis=1)[:, 0]
+            j = common.site_from_uniform(u01[:, 0:1], n)
+            u_j = jnp.take_along_axis(u, j, axis=1)
+            s_j = jnp.take_along_axis(sf, j, axis=1)
             de = 2.0 * s_j * u_j
             p_j = common.flip_probability(de, temp, pwl_table)
-            accept = u01[:, 1] < p_j
+            accept = u01[:, 1:2] < p_j
         else:
             de_all = 2.0 * sf * u            # (R, N)
-            p_all = common.flip_probability(de_all, temp[:, None], pwl_table)
-            j_rw, total, degenerate = common.roulette_pick(p_all, u01[:, 2], lane)
+            p_all = common.flip_probability(de_all, temp, pwl_table)
+            j_rw, total, degenerate = common.roulette_pick(
+                p_all, u01[:, 2:3], lane)
             if uniformized:
-                accept = jnp.where(degenerate, False,
-                                   u01[:, 3] * jnp.float32(n) < total)
+                accept = ~degenerate & (u01[:, 3:4] * jnp.float32(n) < total)
                 j = j_rw
             else:
-                j_fb = common.site_from_uniform(u01[:, 0], n)
-                p_fb = jnp.take_along_axis(p_all, j_fb[:, None], axis=1)[:, 0]
-                accept = jnp.where(degenerate, u01[:, 1] < p_fb, True)
+                j_fb = common.site_from_uniform(u01[:, 0:1], n)
+                p_fb = jnp.take_along_axis(p_all, j_fb, axis=1)
+                accept = ~degenerate | (u01[:, 1:2] < p_fb)
                 j = jnp.where(degenerate, j_fb, j_rw)
-            de = jnp.take_along_axis(de_all, j[:, None], axis=1)[:, 0]
-        s_old = jnp.take_along_axis(sf, j[:, None], axis=1)[:, 0]
+            de = jnp.take_along_axis(de_all, j, axis=1)
+        s_old = jnp.take_along_axis(sf, j, axis=1)
         acc_f = accept.astype(jnp.float32)
-        rows = fetch_rows(j)  # (R, N)
-        u = u - (2.0 * acc_f * s_old)[:, None] * rows
-        onehot = jax.nn.one_hot(j, n, dtype=s.dtype)
-        s = jnp.where(accept[:, None], (s * (1 - 2 * onehot)).astype(s.dtype), s)
-        e = e + acc_f * de
-        nf = nf + accept.astype(jnp.int32)
+        rows = fetch_rows(j[:, 0])  # (R, N)
+        u = u - (2.0 * acc_f * s_old) * rows
+        onehot = jax.nn.one_hot(j[:, 0], n, dtype=s.dtype)
+        s = jnp.where(accept, (s * (1 - 2 * onehot)).astype(s.dtype), s)
+        e = e + (acc_f * de)[:, 0]
+        nf = nf + accept[:, 0].astype(jnp.int32)
         better = e < be
         be = jnp.where(better, e, be)
         bs = jnp.where(better[:, None], s, bs)

@@ -5,12 +5,13 @@ keeps u in BRAM and read-modify-writes it after every flip. A literal
 one-flip-per-XLA-op loop would round-trip u, s through HBM every step; this
 kernel keeps the coupling tile J, the local fields u, and the spins s resident
 in VMEM across ``T`` consecutive MCMC steps, so per-step HBM traffic drops to
-zero for N ≤ ~2800 (f32 J; 16 MiB VMEM) — the same "compute-bound, not
-memory-bound" crossover the paper demonstrates in Fig. 14.
+zero while J fits VMEM (f32 J: 16 MiB at N=2000, of a v5e core's 128 MiB)
+— the same "compute-bound, not memory-bound" crossover the paper
+demonstrates in Fig. 14.
 
 Per-step work is O(br·N) (DESIGN.md §Backends): the incremental update
 u ← u − 2 J[j,:] s_j_old (Eq. 27/31) fetches row J[j] with one per-replica
-``pl.ds`` dynamic slice of the VMEM-resident J. The historical one-hot × J
+``pl.ds`` row read of the VMEM-resident J. The historical one-hot × J
 MXU gather — an O(br·N²) contraction per step — survives only as the opt-in
 ``gather="onehot"`` heuristic for tiny N, where a single small matmul beats
 ``br`` sequential DMA-issued row reads.
@@ -32,16 +33,18 @@ Local-field *initialization* from planes is the separate popcount kernel
 the planes stay in HBM (``memory_space=ANY`` — never blocked into the
 pipeline) and each step's selected row streams into a 2-slot VMEM scratch via
 ``pltpu.make_async_copy`` DMAs, double-buffered across the replica apply
-loop — while replica r's (B, 1, W) row tile is decoded and FMA'd, the DMA
-for replica r+1's row is already in flight. VMEM then holds only the sweep
-state plus two row tiles (O(B·N/32) words), so the N-ceiling is set by HBM
-capacity, not VMEM: N=16384 at B=1 is a 64 MiB plane store streamed at
-~2·B·N/32 words/step against the same O(N) VPU work. The decoded row goes
-through the identical ``common.decode_bitplane_rows`` expansion, so streamed
-trajectories are exactly equal to the VMEM-bitplane and dense paths (the
-parity tier asserts ``assert_array_equal``). The DMA pattern runs under
-interpret mode too (jax 0.4.37 emulates ``make_async_copy`` + semaphores),
-so the tested path on CPU is the compiled path on TPU.
+loop — while replica r's row is decoded and FMA'd, the DMA for replica
+r+1's row is already in flight. A DMA moves whole (8, 128) tiles of the HBM
+layout, so each fetch copies the aligned (B, 8, W) row group and the decode
+reads its row out of VMEM. VMEM then holds only the sweep state plus two row
+groups, so the N-ceiling is set by HBM capacity, not VMEM: N=16384 at B=1 is
+a 64 MiB plane store streamed at 2·B·8·W words/step against the same O(N)
+VPU work. The decoded row goes through the identical
+``common.decode_bitplane_rows`` expansion, so streamed trajectories are
+exactly equal to the VMEM-bitplane and dense paths (the parity tier asserts
+``assert_array_equal``). The DMA pattern runs under interpret mode too (the
+interpreter emulates ``make_async_copy`` + semaphores), so the tested path
+on CPU is the compiled path on TPU.
 
 Feature parity with ``core.mcmc``: both modes (RSA random-scan, RWA
 roulette-wheel with hierarchical lane-scan selection), the uniformized-RWA
@@ -56,8 +59,16 @@ the next selection. Randomness is supplied as a precomputed (T, R, 4) tensor
 of uniforms — (site, accept, roulette, uniformize) streams — from the
 stateless threefry RNG, so the kernel stays deterministic and replayable.
 
-Grid: replica blocks; J is broadcast (index_map pins block 0) so the pipeline
-loads it once per program.
+Grid: replica blocks ("parallel"). A VMEM-resident store is a whole-array
+VMEM operand — copied in once, single-buffered — and each ``pallas_call``
+requests the scoped VMEM its own buffers need (``common.vmem_limit``).
+
+Mosaic lowering rules the kernels follow: the sweep state (u, s, best s)
+lives in VMEM refs and is read and written by row (``ref[pl.ds(r, 1), :]``);
+per-replica scalars (site, coefficient) are taken from (br, 1) columns by
+masked reduction; per-step scalars of the colored schedule come from SMEM;
+the roulette's prefix sums are ``common.prefix_sum`` (no ``cumsum``); the
+PWL segment sweep is a static unroll; no value-level ``dynamic_slice``.
 """
 from __future__ import annotations
 
@@ -79,39 +90,54 @@ COUPLING_MODES = coupling_store.KERNEL_COUPLING_MODES
 PLANE_MODES = coupling_store.KERNEL_PLANE_MODES
 
 
+#: Rows per HBM→VMEM plane DMA: the sublane tile of the HBM layout.
+ROW_GROUP = common.SUBLANE_TILE
+
+
 def _dense_layout(couplings, n, br, coalesce):
-    """VMEM-resident (N, N) f32 J, broadcast to every replica block."""
-    return [pl.BlockSpec((n, n), lambda i: (0, 0))], [couplings], []
+    """VMEM-resident (N, N) J, copied in once for all replica blocks (a
+    whole-array VMEM operand is single-buffered; a grid-invariant block
+    would be double-buffered for nothing)."""
+    return ([pl.BlockSpec(memory_space=pltpu.VMEM)], [couplings], [],
+            common.vmem_bytes(couplings.shape, couplings.dtype))
 
 
 def _bitplane_layout(couplings, n, br, coalesce):
-    """VMEM-resident packed planes: pos/neg (B, N, W) broadcast."""
-    bp, _, w = couplings.pos.shape
-    return ([pl.BlockSpec((bp, n, w), lambda i: (0, 0, 0)),
-             pl.BlockSpec((bp, n, w), lambda i: (0, 0, 0))],
-            [couplings.pos, couplings.neg], [])
+    """VMEM-resident packed planes: pos/neg (B, N, W), copied in once."""
+    vm = pl.BlockSpec(memory_space=pltpu.VMEM)
+    return ([vm, vm], [couplings.pos, couplings.neg], [],
+            2 * common.vmem_bytes(couplings.pos.shape, jnp.uint32))
 
 
 def _bitplane_hbm_layout(couplings, n, br, coalesce):
     """HBM-resident planes: never enter the block pipeline (ANY pins them to
-    HBM); the kernel streams (B, 1, W) row tiles into a 2-slot VMEM scratch
+    HBM); the kernel streams row tiles into a 2-slot VMEM scratch
     double-buffer with one DMA semaphore per (slot, sign) in-flight copy.
-    With coalescing, a (br, N) f32 row cache holds the step's decoded unique
-    rows so duplicate selections replay a VMEM read instead of a second DMA."""
-    bp, _, w = couplings.pos.shape
-    scratch = [pltpu.VMEM((2, bp, 1, w), jnp.uint32),  # pos row tiles
-               pltpu.VMEM((2, bp, 1, w), jnp.uint32),  # neg row tiles
-               pltpu.SemaphoreType.DMA((2, 2))]        # (slot, sign) DMAs
+    HBM arrays are tiled in (8, 128) blocks and a DMA moves whole row tiles,
+    so each fetch copies the aligned (B, 8, W) group holding the row (rows
+    padded to a multiple of 8 first when N is not). With coalescing, a
+    (br, N) f32 row cache holds the step's decoded unique rows so duplicate
+    selections replay a VMEM read instead of a second DMA."""
+    bp, rows, w = couplings.pos.shape
+    planes = [couplings.pos, couplings.neg]
+    if rows % ROW_GROUP:
+        pad = ((0, 0), (0, common.round_up(rows, ROW_GROUP) - rows), (0, 0))
+        planes = [jnp.pad(x, pad) for x in planes]
+    tile = (2, bp, ROW_GROUP, w)
+    scratch = [pltpu.VMEM(tile, jnp.uint32),          # pos row groups
+               pltpu.VMEM(tile, jnp.uint32),          # neg row groups
+               pltpu.SemaphoreType.DMA((2, 2))]       # (slot, sign) DMAs
+    nbytes = 2 * common.vmem_bytes(tile, jnp.uint32)
     if coalesce:
         scratch.append(pltpu.VMEM((br, n), jnp.float32))  # decoded row cache
-    return ([pl.BlockSpec(memory_space=pltpu.ANY),
-             pl.BlockSpec(memory_space=pltpu.ANY)],
-            [couplings.pos, couplings.neg], scratch)
+        nbytes += common.vmem_bytes((br, n), jnp.float32)
+    return ([pl.BlockSpec(memory_space=pl.ANY),
+             pl.BlockSpec(memory_space=pl.ANY)], planes, scratch, nbytes)
 
 
 #: Kernel-side half of the coupling-store contract: resolved format name →
-#: (in_specs, operands, scratch_shapes) for the J store. The host-side half
-#: is ``core.coupling.CouplingStore.build``.
+#: (in_specs, operands, scratch_shapes, VMEM bytes) for the J store. The
+#: host-side half is ``core.coupling.CouplingStore.build``.
 _STORE_LAYOUTS = {
     "dense": _dense_layout,
     "bitplane": _bitplane_layout,
@@ -119,81 +145,39 @@ _STORE_LAYOUTS = {
 }
 
 
-def _gather_scalars(x: jax.Array, sites: jax.Array, br: int) -> jax.Array:
-    """vals[r] = x[r, sites[r]] via per-replica (1, 1) dynamic slices — O(br)
-    work in place of a (br, N) one-hot masked reduction."""
-
-    def body(rix, vals):
-        v = jax.lax.dynamic_slice(x, (rix, sites[rix]), (1, 1))
-        return jax.lax.dynamic_update_slice(vals, v[0], (rix,))
-
-    return jax.lax.fori_loop(0, br, body, jnp.zeros((br,), x.dtype))
+def _pick(col: jax.Array, i) -> jax.Array:
+    """Scalar ``col[i, 0]`` of an (br, 1) column for a traced row ``i`` —
+    masked reduction (one term plus zeros, exact), the lowerable form of a
+    per-replica scalar read."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, col.shape, 0)
+    return jnp.sum(jnp.where(rows == i, col, jnp.zeros((), col.dtype)))
 
 
-def _gather_scalar_pair(a: jax.Array, b: jax.Array, sites: jax.Array,
-                        br: int) -> tuple[jax.Array, jax.Array]:
-    """(a[r, sites[r]], b[r, sites[r]]) for every replica in one loop."""
-
-    def body(rix, carry):
-        va, vb = carry
-        av = jax.lax.dynamic_slice(a, (rix, sites[rix]), (1, 1))
-        bv = jax.lax.dynamic_slice(b, (rix, sites[rix]), (1, 1))
-        return (jax.lax.dynamic_update_slice(va, av[0], (rix,)),
-                jax.lax.dynamic_update_slice(vb, bv[0], (rix,)))
-
-    init = (jnp.zeros((br,), a.dtype), jnp.zeros((br,), b.dtype))
-    return jax.lax.fori_loop(0, br, body, init)
-
-
-def _kernel(*refs, num_steps: int, mode: str, uniformized: bool,
-            gather: str, lane: int, has_pwl: bool, coupling: str,
-            coalesce: bool):
-    streamed = coupling == "bitplane_hbm"
-    cache_scr = None
-    if streamed:
-        # HBM-streaming scratch: 2-slot (double-buffered) row tiles per sign
-        # plane plus one DMA semaphore per (slot, sign) in-flight copy; the
-        # coalesced path adds the (br, N) decoded-row cache.
-        if coalesce:
-            pos_scr, neg_scr, row_sems, cache_scr = refs[-4:]
-            refs = refs[:-4]
-        else:
-            pos_scr, neg_scr, row_sems = refs[-3:]
-            refs = refs[:-3]
-    num_j = 2 if coupling in PLANE_MODES else 1
-    j_refs = refs[:num_j]
-    (u0_ref, s0_ref, e0_ref, unif_ref, temp_ref) = refs[num_j:num_j + 5]
-    if has_pwl:
-        pwl_ref = refs[num_j + 5]
-        tbl = pwl_ref[...].astype(jnp.float32)
-    else:
-        tbl = None
-    (u_out, s_out, e_out, be_out, bs_out, nf_out,
-     rf_out) = refs[num_j + 5 + int(has_pwl):]
-    n = u0_ref.shape[1]
-    br = u0_ref.shape[0]
-    # Only the opt-in MXU path materializes J as a value; the default O(N)
-    # path reads single rows straight off the ref(s).
-    J = j_refs[0][...].astype(jnp.float32) if gather == "onehot" else None
+def _row_store(j_refs, coupling: str, n: int, scratch=()):
+    """The kernel's coupling-row reader: ``fetch_row(jr)`` → the (1, N) f32
+    row ``jr`` off the VMEM-resident store, and for the HBM tier
+    ``stream_start(slot, jr)`` / ``stream_wait_decode(slot, jr)`` around the
+    2-slot DMA double buffer. One decode for every tier ⇒ identical rows."""
 
     def fetch_row(jr):
-        """(1, N) f32 coupling row jr — `pl.ds` off the VMEM-resident store."""
         if coupling == "bitplane":
             pos_ref, neg_ref = j_refs
-            pr = pos_ref[:, pl.ds(jr, 1), :]  # (B, 1, W) packed words
-            nr = neg_ref[:, pl.ds(jr, 1), :]
-            return common.decode_bitplane_rows(pr, nr, n)
+            return common.decode_bitplane_rows(pos_ref[:, pl.ds(jr, 1), :],
+                                               neg_ref[:, pl.ds(jr, 1), :], n)
         return j_refs[0][pl.ds(jr, 1), :].astype(jnp.float32)
 
     def stream_dmas(slot, jr):
-        """The two (B, 1, W) HBM→VMEM row-tile copies for site jr into
+        """The two (B, 8, W) HBM→VMEM copies of site jr's row group into
         double-buffer ``slot`` (descriptors are rebuilt for wait() — the
         canonical make_async_copy pattern)."""
         pos_ref, neg_ref = j_refs
-        return (pltpu.make_async_copy(pos_ref.at[:, pl.ds(jr, 1), :],
-                                      pos_scr.at[slot], row_sems.at[slot, 0]),
-                pltpu.make_async_copy(neg_ref.at[:, pl.ds(jr, 1), :],
-                                      neg_scr.at[slot], row_sems.at[slot, 1]))
+        pos_scr, neg_scr, sems = scratch
+        rows = pl.ds(pl.multiple_of(jr // ROW_GROUP * ROW_GROUP, ROW_GROUP),
+                     ROW_GROUP)
+        return (pltpu.make_async_copy(pos_ref.at[:, rows, :],
+                                      pos_scr.at[slot], sems.at[slot, 0]),
+                pltpu.make_async_copy(neg_ref.at[:, rows, :],
+                                      neg_scr.at[slot], sems.at[slot, 1]))
 
     def stream_start(slot, jr):
         for dma in stream_dmas(slot, jr):
@@ -202,87 +186,105 @@ def _kernel(*refs, num_steps: int, mode: str, uniformized: bool,
     def stream_wait_decode(slot, jr):
         """Block on slot's row DMAs, then the same in-register bit expansion
         as the VMEM path — identical decode ⇒ identical trajectories."""
+        pos_scr, neg_scr, _ = scratch
         for dma in stream_dmas(slot, jr):
             dma.wait()
-        return common.decode_bitplane_rows(pos_scr[slot], neg_scr[slot], n)
-    u = u0_ref[...].astype(jnp.float32)     # (br, N)
-    s = s0_ref[...].astype(jnp.float32)     # (br, N) ±1
-    e = e0_ref[...].astype(jnp.float32)[:, 0]  # (br,)
+        row = pl.ds(jr % ROW_GROUP, 1)
+        return common.decode_bitplane_rows(pos_scr[slot, :, row, :],
+                                           neg_scr[slot, :, row, :], n)
+
+    return fetch_row, stream_start, stream_wait_decode
+
+
+def _kernel(*refs, num_steps: int, mode: str, uniformized: bool,
+            gather: str, lane: int, has_pwl: bool, coupling: str,
+            coalesce: bool):
+    streamed = coupling == "bitplane_hbm"
+    num_j = 2 if coupling in PLANE_MODES else 1
+    j_refs = refs[:num_j]
+    u0_ref, s0_ref, e0_ref, unif_ref, temp_ref = refs[num_j:num_j + 5]
+    rest = refs[num_j + 5:]
+    tbl = rest[0][...].astype(jnp.float32) if has_pwl else None
+    rest = rest[int(has_pwl):]
+    u_out, s_out, e_out, be_out, bs_out, nf_out, rf_out = rest[:7]
+    # State scratch: the f32 spins and best spins (u lives in u_out), then
+    # the HBM tier's tiles + semaphores and (coalesced) decoded-row cache.
+    s_scr, bs_scr = rest[7:9]
+    store_scr = rest[9:12] if streamed else ()
+    cache_scr = rest[12] if streamed and coalesce else None
+    br, n = u0_ref.shape
+    fetch_row, stream_start, stream_wait_decode = _row_store(
+        j_refs, coupling, n, store_scr)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (br, n), 1)
+
+    def gather_sites(x, j):
+        """x[r, j[r]] as an (br, 1) column by masked row reduction (exact)."""
+        return jnp.sum(jnp.where(lanes == j, x, 0.0), axis=1, keepdims=True)
+
+    def flip_probability(de, temp):
+        return common.flip_probability(de, temp, tbl, pwl_select="select")
+
+    u_out[...] = u0_ref[...].astype(jnp.float32)
+    s_scr[...] = s0_ref[...].astype(jnp.float32)
+    bs_scr[...] = s_scr[...]
 
     def step(t, carry):
-        u, s, e, be, bs, nf, rf = carry
-        temp = temp_ref[t]                  # (br,) per-replica ladder rung
-        u_site = unif_ref[t, :, 0]
-        u_acc = unif_ref[t, :, 1]
-        u_rou = unif_ref[t, :, 2]
-        u_uni = unif_ref[t, :, 3]
+        e, be, nf, rf = carry                # (br, 1) columns
+        unif = unif_ref[t]                   # (br, 4) (site, acc, rou, uni)
+        u_site, u_acc = unif[:, 0:1], unif[:, 1:2]
+        u_rou, u_uni = unif[:, 2:3], unif[:, 3:4]
+        temp = temp_ref[t]                   # (br, 1) per-replica rung
+        u = u_out[...]
+        s = s_scr[...]
         if mode == "rsa":
             j = common.site_from_uniform(u_site, n)
-            s_old, u_j = _gather_scalar_pair(s, u, j, br)
-            de = 2.0 * s_old * u_j
-            p_j = common.flip_probability(de, temp, tbl)
-            accept_b = u_acc < p_j
+            s_old = gather_sites(s, j)
+            de = 2.0 * s_old * gather_sites(u, j)
+            accept_b = u_acc < flip_probability(de, temp)
         else:
             de_all = 2.0 * s * u
-            p_all = common.flip_probability(de_all, temp[:, None], tbl)
+            p_all = flip_probability(de_all, temp)
             j_rw, total, degenerate = common.roulette_pick(p_all, u_rou, lane)
             if uniformized:
                 # Null transition with prob 1 − W/W*, W* = N (§IV-B3c).
-                accept_b = jnp.where(degenerate, False,
-                                     u_uni * jnp.float32(n) < total)
+                accept_b = ~degenerate & (u_uni * jnp.float32(n) < total)
                 j = j_rw
             else:
                 # Degenerate-W fallback: one random-scan update (Alg. 1 l. 10-14).
                 j_fb = common.site_from_uniform(u_site, n)
-                p_fb = _gather_scalars(p_all, j_fb, br)
-                accept_b = jnp.where(degenerate, u_acc < p_fb, True)
+                p_fb = gather_sites(p_all, j_fb)
+                accept_b = ~degenerate | (u_acc < p_fb)
                 j = jnp.where(degenerate, j_fb, j_rw)
-            de, s_old = _gather_scalar_pair(de_all, s, j, br)
+            de = gather_sites(de_all, j)
+            s_old = gather_sites(s, j)
         accept = accept_b.astype(jnp.float32)
         e = e + accept * de
         nf = nf + accept_b.astype(jnp.int32)
         better = e < be
         be = jnp.where(better, e, be)
+        coef = 2.0 * accept * s_old          # u ← u − coef·J[j] (Eq. 27/31)
+        s_scr[...] = jnp.where((lanes == j) & accept_b, -s, s)
         if gather == "onehot":
             rf = rf + 1                      # one row materialized per replica
-            iota = jax.lax.broadcasted_iota(jnp.int32, (br, n), 1)
-            onehot = (iota == j[:, None]).astype(jnp.float32)
-            rows = jax.lax.dot_general(onehot, J, (((1,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-            u = u - (2.0 * accept * s_old)[:, None] * rows
-            s = s * (1.0 - 2.0 * accept[:, None] * onehot)
-            bs = jnp.where(better[:, None], s, bs)
+            onehot = (lanes == j).astype(jnp.float32)
+            rows = jax.lax.dot_general(
+                onehot, j_refs[0][...].astype(jnp.float32),
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            u_out[...] = u - coef * rows
         else:
             # Asynchronous apply, one replica at a time: an O(N) row FMA
-            # straight off the J ref, a scalar spin flip, and a
-            # copy-on-improve of best_spins (lax.cond so the (1, N) copy is
-            # only paid when the replica actually improved).
-            def apply_row(rix, jr, row, u, s, bs):
-                """Consume replica rix's (1, N) coupling row — the arithmetic
-                shared verbatim by the VMEM-fetch and HBM-streamed drivers."""
-                coef = 2.0 * accept[rix] * s_old[rix]
-                u_row = jax.lax.dynamic_slice(u, (rix, 0), (1, n))
-                u = jax.lax.dynamic_update_slice(u, u_row - coef * row,
-                                                 (rix, 0))
-                new_sj = (s_old[rix] * (1.0 - 2.0 * accept[rix])).reshape(1, 1)
-                s = jax.lax.dynamic_update_slice(s, new_sj, (rix, jr))
-                bs = jax.lax.cond(
-                    better[rix],
-                    lambda b, s=s: jax.lax.dynamic_update_slice(
-                        b, jax.lax.dynamic_slice(s, (rix, 0), (1, n)),
-                        (rix, 0)),
-                    lambda b: b, bs)
-                return (u, s, bs)
+            # into that replica's field row.
+            def apply_row(rix, row):
+                u_out[pl.ds(rix, 1), :] = (u_out[pl.ds(rix, 1), :]
+                                           - _pick(coef, rix) * row)
 
             if streamed and coalesce:
-                # Reuse-aware streaming (ROADMAP item 4): DMA each *unique*
-                # selected row exactly once — still double-buffered across
-                # the dynamic-trip fetch loop — into the (br, N) decoded-row
-                # cache, then apply replicas in their original order reading
-                # the cache. The decoded row depends only on the site, so
-                # fetch-once-broadcast is byte-identical to fetch-per-replica
-                # and the trajectory cannot move; only rf (rows fetched)
-                # drops from br to nu per step.
+                # Reuse-aware streaming: DMA each *unique* selected row
+                # exactly once — still double-buffered across the fetch
+                # loop — into the (br, N) decoded-row cache, then apply
+                # replicas reading the cache. The decoded row depends only on
+                # the site, so the trajectory cannot move; only rf (rows
+                # fetched) drops from br to nu per step.
                 nu, usite, uo, fetched = common.coalesce_rows(j)
                 rf = rf + fetched
 
@@ -291,66 +293,59 @@ def _kernel(*refs, num_steps: int, mode: str, uniformized: bool,
 
                     @pl.when(m + 1 < nu)
                     def _():
-                        nxt = jnp.minimum(m + 1, br - 1)
-                        stream_start(jax.lax.rem(m + 1, 2), usite[nxt])
+                        stream_start(1 - slot, _pick(usite, m + 1))
 
                     cache_scr[pl.ds(m, 1), :] = stream_wait_decode(
-                        slot, usite[m])
+                        slot, _pick(usite, m))
                     return c
 
-                stream_start(0, usite[0])
+                stream_start(0, _pick(usite, 0))
                 jax.lax.fori_loop(0, nu, fetch_one, 0)
 
-                def apply_one(rix, carry):
-                    u, s, bs = carry
-                    row = cache_scr[pl.ds(uo[rix], 1), :]  # (1, N)
-                    return apply_row(rix, j[rix], row, u, s, bs)
+                def apply_one(rix, c):
+                    apply_row(rix, cache_scr[pl.ds(_pick(uo, rix), 1), :])
+                    return c
             elif streamed:
                 rf = rf + 1
                 # Double-buffered HBM streaming: replica r+1's row tiles are
                 # DMA'd into the other scratch slot while replica r's row is
-                # decoded and applied (sites j are all known before the apply
-                # loop, and replicas are independent, so the prefetch can
-                # never read a stale site).
-                def apply_one(rix, carry):
-                    u, s, bs = carry
-                    jr = j[rix]
+                # decoded and applied (sites are all known before the apply
+                # loop, and replicas are independent).
+                def apply_one(rix, c):
                     slot = jax.lax.rem(rix, 2)
 
                     @pl.when(rix + 1 < br)
                     def _():
-                        nxt = jnp.minimum(rix + 1, br - 1)
-                        stream_start(jax.lax.rem(rix + 1, 2), j[nxt])
+                        stream_start(1 - slot, _pick(j, rix + 1))
 
-                    row = stream_wait_decode(slot, jr)  # (1, N)
-                    return apply_row(rix, jr, row, u, s, bs)
+                    apply_row(rix, stream_wait_decode(slot, _pick(j, rix)))
+                    return c
 
-                stream_start(0, j[0])
+                stream_start(0, _pick(j, 0))
             else:
                 rf = rf + 1
 
-                def apply_one(rix, carry):
-                    u, s, bs = carry
-                    jr = j[rix]
-                    row = fetch_row(jr)  # (1, N)
-                    return apply_row(rix, jr, row, u, s, bs)
+                def apply_one(rix, c):
+                    apply_row(rix, fetch_row(_pick(j, rix)))
+                    return c
 
-            u, s, bs = jax.lax.fori_loop(0, br, apply_one, (u, s, bs))
-        return (u, s, e, be, bs, nf, rf)
+            jax.lax.fori_loop(0, br, apply_one, 0)
+        bs_scr[...] = jnp.where(better, s_scr[...], bs_scr[...])
+        return e, be, nf, rf
 
-    init = (u, s, e, e, s, jnp.zeros((br,), jnp.int32),
-            jnp.zeros((br,), jnp.int32))
-    u, s, e, be, bs, nf, rf = jax.lax.fori_loop(0, num_steps, step, init)
-    u_out[...] = u
-    s_out[...] = s.astype(s_out.dtype)
-    e_out[...] = e[:, None]
-    be_out[...] = be[:, None]
-    bs_out[...] = bs.astype(bs_out.dtype)
-    nf_out[...] = nf[:, None]
-    rf_out[...] = rf[:, None]
+    e = e0_ref[...].astype(jnp.float32)
+    zeros = jnp.zeros((br, 1), jnp.int32)
+    e, be, nf, rf = jax.lax.fori_loop(0, num_steps, step, (e, e, zeros, zeros))
+    s_out[...] = s_scr[...].astype(s_out.dtype)
+    bs_out[...] = bs_scr[...].astype(bs_out.dtype)
+    e_out[...] = e
+    be_out[...] = be
+    nf_out[...] = nf
+    rf_out[...] = rf
 
 
-def _colored_kernel(*refs, num_steps: int, has_pwl: bool, coupling: str):
+def _colored_kernel(*refs, num_steps: int, has_pwl: bool, coupling: str,
+                    n: int):
     """Graph-colored block sweep: per step, every spin of the scheduled color
     class accepts an independent heat-bath flip off the live local fields,
     then the accepted subset's rank-1 field updates are applied through the
@@ -361,113 +356,135 @@ def _colored_kernel(*refs, num_steps: int, has_pwl: bool, coupling: str):
     uniformized) does not enter: class membership replaces spin selection,
     so colored trajectories are mode-independent by construction.
 
-    The driver hands the class schedule as a (T, 3) int32 ``sched`` tensor —
-    per step the lane-aligned window start ``w``, the class offset, and the
-    class size in the color-sorted (permuted) spin order — so the kernel
-    slices one static-width window per step and masks to the live class.
+    The driver hands the class schedule as a (T, 3) int32 ``sched`` table in
+    SMEM — per step the window start ``w`` (a multiple of 128), the class
+    offset, and the class size in the color-sorted (permuted) spin order —
+    so the kernel reads one static-width window per step at a lane-aligned
+    dynamic offset and masks it to the live class. The state is padded to a
+    lane multiple (``n`` is the real spin count; the pad is never in a class).
     """
     streamed = coupling == "bitplane_hbm"
-    if streamed:
-        pos_scr, neg_scr, row_sems = refs[-3:]
-        refs = refs[:-3]
     num_j = 2 if coupling in PLANE_MODES else 1
     j_refs = refs[:num_j]
     (u0_ref, s0_ref, e0_ref, unif_ref, temp_ref,
      sched_ref) = refs[num_j:num_j + 6]
-    if has_pwl:
-        pwl_ref = refs[num_j + 6]
-        tbl = pwl_ref[...].astype(jnp.float32)
-    else:
-        tbl = None
-    (u_out, s_out, e_out, be_out, bs_out, nf_out,
-     rf_out) = refs[num_j + 6 + int(has_pwl):]
-    n = u0_ref.shape[1]
+    rest = refs[num_j + 6:]
+    tbl = rest[0][...].astype(jnp.float32) if has_pwl else None
+    rest = rest[int(has_pwl):]
+    u_out, s_out, e_out, be_out, bs_out, nf_out, rf_out = rest[:7]
+    s_scr, bs_scr = rest[7:9]
+    store_scr = rest[9:12] if streamed else ()
     br = u0_ref.shape[0]
     win = unif_ref.shape[2]
+    fetch_row, stream_start, stream_wait_decode = _row_store(
+        j_refs, coupling, n, store_scr)
+    if streamed:
+        # The colored fetch is gated per slot (no double-buffer overlap):
+        # one slot, started and waited back to back.
+        def fetch_row(jr):
+            stream_start(0, jr)
+            return stream_wait_decode(0, jr)
 
-    def fetch_row(jr):
-        """(1, N) f32 coupling row jr — identical decode to the single-flip
-        kernel, so the colored oracle can require bit-exact trajectories."""
-        if coupling == "bitplane":
-            pos_ref, neg_ref = j_refs
-            return common.decode_bitplane_rows(
-                pos_ref[:, pl.ds(jr, 1), :], neg_ref[:, pl.ds(jr, 1), :], n)
-        if streamed:
-            pos_ref, neg_ref = j_refs
-            dmas = (pltpu.make_async_copy(pos_ref.at[:, pl.ds(jr, 1), :],
-                                          pos_scr.at[0], row_sems.at[0, 0]),
-                    pltpu.make_async_copy(neg_ref.at[:, pl.ds(jr, 1), :],
-                                          neg_scr.at[0], row_sems.at[0, 1]))
-            for dma in dmas:
-                dma.start()
-            for dma in dmas:
-                dma.wait()
-            return common.decode_bitplane_rows(pos_scr[0], neg_scr[0], n)
-        return j_refs[0][pl.ds(jr, 1), :].astype(jnp.float32)
-
-    u = u0_ref[...].astype(jnp.float32)
-    s = s0_ref[...].astype(jnp.float32)
-    e = e0_ref[...].astype(jnp.float32)[:, 0]
+    ids = jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)
+    u_out[...] = u0_ref[...].astype(jnp.float32)
+    s_scr[...] = s0_ref[...].astype(jnp.float32)
+    bs_scr[...] = s_scr[...]
+    rf_out[...] = jnp.zeros_like(rf_out)
 
     def step(t, carry):
-        u, s, e, be, bs, nf, rf = carry
-        temp = temp_ref[t]                       # (br,)
-        w = sched_ref[t, 0]
-        off = sched_ref[t, 1]
-        size = sched_ref[t, 2]
-        u_win = jax.lax.dynamic_slice(u, (0, w), (br, win))
-        s_win = jax.lax.dynamic_slice(s, (0, w), (br, win))
+        e, be, nf = carry                        # (br, 1) columns
+        temp = temp_ref[t]                       # (br, 1)
+        w = pl.multiple_of(sched_ref[3 * t], common.LANE_TILE)
+        off = sched_ref[3 * t + 1]
+        size = sched_ref[3 * t + 2]
+        u_win = u_out[:, pl.ds(w, win)]
+        s_win = s_scr[:, pl.ds(w, win)]
         de = 2.0 * s_win * u_win
-        p = common.flip_probability(de, temp[:, None], tbl)
+        p = common.flip_probability(de, temp, tbl, pwl_select="select")
         idx = jax.lax.broadcasted_iota(jnp.int32, (br, win), 1) + w
         valid = (idx >= off) & (idx < off + size)
         accept = (unif_ref[t] < p) & valid
         acc_f = accept.astype(jnp.float32)
-        e = e + jnp.sum(acc_f * de, axis=1)
-        nf = nf + jnp.sum(accept.astype(jnp.int32), axis=1)
-        s = jax.lax.dynamic_update_slice(s, s_win * (1.0 - 2.0 * acc_f),
-                                         (0, w))
+        e = e + jnp.sum(acc_f * de, axis=1, keepdims=True)
+        nf = nf + jnp.sum(accept.astype(jnp.int32), axis=1, keepdims=True)
+        s_scr[:, pl.ds(w, win)] = s_win * (1.0 - 2.0 * acc_f)
 
-        def apply_slot(k, carry):
+        def apply_slot(k, c):
             # One class member per iteration: fetch its row once — the fetch
             # is shared by every replica, cross-replica coalescing for free —
             # and FMA it into all br field rows, gated so idle slots cost
             # nothing (and the streamed tier skips the DMA entirely).
-            u, rf = carry
-            acc_k = jax.lax.dynamic_slice(acc_f, (0, k), (br, 1))  # (br, 1)
-            s_old_k = jax.lax.dynamic_slice(s_win, (0, k), (br, 1))
-            anyacc = jnp.sum(acc_k) > 0.0
+            acc_k = common.take_lane(acc_f, k)                  # (br, 1)
+            s_old_k = common.take_lane(s_win, k)
 
-            def do(carry):
-                u, rf = carry
-                row = fetch_row(w + k)                 # (1, N)
-                u = u - (2.0 * acc_k * s_old_k) * row
+            @pl.when(jnp.sum(acc_k) > 0.0)
+            def _():
+                row = fetch_row(w + k)                           # (1, N)
+                u_out[:, :n] = u_out[:, :n] - (2.0 * acc_k * s_old_k) * row
                 # Attribute the single shared fetch to the lowest-index
                 # accepting replica (the coalesce_rows convention), so the
                 # block sum of rf is the true unique-row traffic.
-                ids = jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)
                 first = jnp.min(jnp.where(acc_k > 0.0, ids, br))
-                return u, rf + (ids[:, 0] == first).astype(jnp.int32)
+                rf_out[...] += (ids == first).astype(jnp.int32)
 
-            return jax.lax.cond(anyacc, do, lambda c: c, (u, rf))
+            return c
 
         lo = off - w
-        u, rf = jax.lax.fori_loop(lo, lo + size, apply_slot, (u, rf))
+        jax.lax.fori_loop(lo, lo + size, apply_slot, 0)
         better = e < be
         be = jnp.where(better, e, be)
-        bs = jnp.where(better[:, None], s, bs)
-        return (u, s, e, be, bs, nf, rf)
+        bs_scr[...] = jnp.where(better, s_scr[...], bs_scr[...])
+        return e, be, nf
 
-    init = (u, s, e, e, s, jnp.zeros((br,), jnp.int32),
-            jnp.zeros((br,), jnp.int32))
-    u, s, e, be, bs, nf, rf = jax.lax.fori_loop(0, num_steps, step, init)
-    u_out[...] = u
-    s_out[...] = s.astype(s_out.dtype)
-    e_out[...] = e[:, None]
-    be_out[...] = be[:, None]
-    bs_out[...] = bs.astype(bs_out.dtype)
-    nf_out[...] = nf[:, None]
-    rf_out[...] = rf[:, None]
+    e = e0_ref[...].astype(jnp.float32)
+    e, be, nf = jax.lax.fori_loop(0, num_steps, step,
+                                  (e, e, jnp.zeros((br, 1), jnp.int32)))
+    s_out[...] = s_scr[...].astype(s_out.dtype)
+    bs_out[...] = bs_scr[...].astype(bs_out.dtype)
+    e_out[...] = e
+    be_out[...] = be
+    nf_out[...] = nf
+
+
+def _state_specs(br: int, n: int, t: int, stream_width: int):
+    """Blocks of the per-replica operands — u0, s0, e0, the (T, R, ·)
+    uniforms and the (T, R, 1) temperatures — and of the 7 outputs (u, s,
+    e, best_e, best_s, flips, rows fetched): replica block ``i`` of each."""
+    row = pl.BlockSpec((br, n), lambda i: (i, 0))
+    col = pl.BlockSpec((br, 1), lambda i: (i, 0))
+    ins = [row, row, col,
+           pl.BlockSpec((t, br, stream_width), lambda i: (0, i, 0)),
+           pl.BlockSpec((t, br, 1), lambda i: (0, i, 0))]
+    return ins, [row, row, col, col, row, col, col]
+
+
+def _state_vmem_bytes(br: int, n: int, t: int, stream_width: int) -> int:
+    """VMEM of the pipelined state blocks (double-buffered) + the f32 spin
+    scratch + headroom for the step's (br, N) temporaries."""
+    row = common.vmem_bytes((br, n), jnp.float32)
+    col = common.vmem_bytes((br, 1), jnp.float32)
+    stream = (common.vmem_bytes((t, br, stream_width), jnp.float32)
+              + common.vmem_bytes((t, br, 1), jnp.float32))
+    return 2 * (5 * row + 6 * col + stream) + 2 * row + 8 * row
+
+
+def _out_shapes(r: int, n: int, spin_dtype):
+    return [
+        jax.ShapeDtypeStruct((r, n), jnp.float32),
+        jax.ShapeDtypeStruct((r, n), spin_dtype),
+        jax.ShapeDtypeStruct((r, 1), jnp.float32),
+        jax.ShapeDtypeStruct((r, 1), jnp.float32),
+        jax.ShapeDtypeStruct((r, n), spin_dtype),
+        jax.ShapeDtypeStruct((r, 1), jnp.int32),
+        jax.ShapeDtypeStruct((r, 1), jnp.int32),
+    ]
+
+
+def _compiler_params(nbytes: int):
+    """Replica blocks are independent ("parallel"); the scoped-VMEM request
+    is derived from the kernel's own buffers (``common.vmem_limit``)."""
+    return pltpu.CompilerParams(dimension_semantics=("parallel",),
+                                vmem_limit_bytes=common.vmem_limit(nbytes))
 
 
 @functools.partial(jax.jit, static_argnames=("coupling", "block_r",
@@ -484,12 +501,14 @@ def colored_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
     same decode, no ``dot_general``), but each step updates the whole
     scheduled color class instead of selecting one spin. Spins must already
     be in color-sorted (permuted) order — ``kernels.ops.colored_anneal``
-    owns the permutation. ``uniforms`` is (T, R, S) with S the static
-    lane-aligned class window; ``sched`` is (T, 3) int32 rows of
-    ``(window_start, class_offset, class_size)`` per step. ``rows_fetched``
-    counts each fetched coupling row once, attributed to the lowest-index
-    accepting replica (the row fetch is shared across replicas — colored
-    mode is coalesced by construction on every tier).
+    owns the permutation. ``uniforms`` is (T, R, S) with S the static class
+    window, a multiple of 128; ``sched`` is (T, 3) int32 rows of
+    ``(window_start, class_offset, class_size)`` per step, window starts
+    multiples of 128 with ``start + S ≤ roundup(N, 128)`` (the
+    ``ColoredPlan`` window math). ``rows_fetched`` counts each fetched
+    coupling row once, attributed to the lowest-index accepting replica (the
+    row fetch is shared across replicas — colored mode is coalesced by
+    construction on every tier).
     """
     r, n = fields0.shape
     t = uniforms.shape[0]
@@ -498,55 +517,45 @@ def colored_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
     assert uniforms.shape == (t, r, win) and temps.shape == (t, r)
     assert sched.shape == (t, 3)
     coupling_store.validate_kernel_operand(coupling, couplings, n, "dynamic")
-    br = common.fit_block(r, block_r)
-    grid = (r // br,)
-    in_specs, j_args, scratch_shapes = _STORE_LAYOUTS[coupling](
+    n_pad = common.round_up(n, common.LANE_TILE)
+    if win % common.LANE_TILE or win > n_pad:
+        raise ValueError(f"class window {win} must be a multiple of "
+                         f"{common.LANE_TILE} and ≤ {n_pad}")
+    br = common.replica_block(r, block_r)
+    in_specs, j_args, store_scratch, nbytes = _STORE_LAYOUTS[coupling](
         couplings, n, br, False)
     if coupling == "bitplane_hbm":
-        # The colored fetch is cond-gated (no double-buffer overlap), so only
-        # the 2-slot tile scratch + semaphores of the layout are consumed.
-        scratch_shapes = scratch_shapes[:3]
-    in_specs = in_specs + [
-        pl.BlockSpec((br, n), lambda i: (i, 0)),         # u0
-        pl.BlockSpec((br, n), lambda i: (i, 0)),         # s0
-        pl.BlockSpec((br, 1), lambda i: (i, 0)),         # e0
-        pl.BlockSpec((t, br, win), lambda i: (0, i, 0)),  # uniforms
-        pl.BlockSpec((t, br), lambda i: (0, i)),         # temps
-        pl.BlockSpec((t, 3), lambda i: (0, 0)),          # class schedule
-    ]
-    args = j_args + [fields0, spins0, energy0.reshape(r, 1), uniforms, temps,
-                     sched.astype(jnp.int32)]
+        store_scratch = store_scratch[:3]  # tiles + semaphores, no row cache
+    state_in, out_specs = _state_specs(br, n_pad, t, win)
+    in_specs = in_specs + state_in + [pl.BlockSpec(memory_space=pltpu.SMEM)]
+    # The state is padded to a lane multiple so every window is a
+    # lane-aligned slice; pad spins never join a class.
+    pad = ((0, 0), (0, n_pad - n))
+    args = j_args + [jnp.pad(fields0.astype(jnp.float32), pad),
+                     jnp.pad(spins0, pad, constant_values=1),
+                     energy0.reshape(r, 1), uniforms, temps.reshape(t, r, 1),
+                     sched.astype(jnp.int32).reshape(3 * t)]
     if pwl_table is not None:
         in_specs.append(pl.BlockSpec(pwl_table.shape, lambda i: (0, 0)))
         args.append(pwl_table)
+    nbytes += _state_vmem_bytes(br, n_pad, t, win)
     outs = pl.pallas_call(
         functools.partial(_colored_kernel, num_steps=t,
-                          has_pwl=pwl_table is not None, coupling=coupling),
-        grid=grid,
+                          has_pwl=pwl_table is not None, coupling=coupling,
+                          n=n),
+        grid=(r // br,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, n), jnp.float32),
-            jax.ShapeDtypeStruct((r, n), spins0.dtype),
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-            jax.ShapeDtypeStruct((r, n), spins0.dtype),
-            jax.ShapeDtypeStruct((r, 1), jnp.int32),
-            jax.ShapeDtypeStruct((r, 1), jnp.int32),
-        ],
-        scratch_shapes=scratch_shapes,
+        out_specs=out_specs,
+        out_shape=_out_shapes(r, n_pad, spins0.dtype),
+        scratch_shapes=[pltpu.VMEM((br, n_pad), jnp.float32),
+                        pltpu.VMEM((br, n_pad), jnp.float32)] + store_scratch,
+        compiler_params=_compiler_params(nbytes),
         interpret=interpret,
+        name="colored_sweep",
     )(*args)
     u, s, e, be, bs, nf, rf = outs
-    return u, s, e[:, 0], be[:, 0], bs, nf[:, 0], rf[:, 0]
+    return (u[:, :n], s[:, :n], e[:, 0], be[:, 0], bs[:, :n], nf[:, 0],
+            rf[:, 0])
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -571,18 +580,19 @@ def mcmc_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
     (T, R) per-replica temperatures; pwl_table optional (S+1, 3) LUT from
     ``core.pwl.pwl_table`` (None = exact sigmoid). ``gather``: "dynamic"
     (default, O(N)/step row fetch) or "onehot" (opt-in O(N²)/step MXU
-    contraction for tiny N; dense-only). ``block_r`` clamps to the largest
-    divisor of R. ``coalesce`` (default on; only the HBM-streamed tier is
-    affected — VMEM-resident fetches are free) DMAs each step's *unique*
-    selected rows once and broadcasts the decoded row to every replica that
-    picked it (``common.coalesce_rows``) — bit-identical trajectories, up to
-    br× less row traffic. Returns (fields, spins, energy, best_energy,
-    best_spins, num_flips, rows_fetched) where rows_fetched is the (R,)
-    int32 count of coupling-row fetches each replica block attributed to
-    that replica (uncoalesced paths count one per replica per step; the
-    coalesced stream attributes each unique row to the lowest-index replica
-    selecting it, so the block sum is the unique-row traffic); see
-    ``ref.mcmc_sweep`` for the exact-semantics oracle.
+    contraction for tiny N; dense-only). ``block_r`` is resolved by
+    ``common.replica_block`` (a multiple of 8 dividing R, else all of R).
+    ``coalesce`` (default on; only the HBM-streamed tier is affected —
+    VMEM-resident fetches are free) DMAs each step's *unique* selected rows
+    once and broadcasts the decoded row to every replica that picked it
+    (``common.coalesce_rows``) — bit-identical trajectories, up to br× less
+    row traffic. Returns (fields, spins, energy, best_energy, best_spins,
+    num_flips, rows_fetched) where rows_fetched is the (R,) int32 count of
+    coupling-row fetches each replica block attributed to that replica
+    (uncoalesced paths count one per replica per step; the coalesced stream
+    attributes each unique row to the lowest-index replica selecting it, so
+    the block sum is the unique-row traffic); see ``ref.mcmc_sweep`` for the
+    exact-semantics oracle.
     """
     r, n = fields0.shape
     t = uniforms.shape[0]
@@ -591,55 +601,38 @@ def mcmc_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
     if gather not in ("dynamic", "onehot"):
         raise ValueError(f"gather must be 'dynamic' or 'onehot', got {gather!r}")
     coupling_store.validate_kernel_operand(coupling, couplings, n, gather)
-    br = common.fit_block(r, block_r)
+    br = common.replica_block(r, block_r)
     lane = common.default_lane(n) if lane is None else lane
     if n % lane:
         raise ValueError(f"N={n} not divisible by lane={lane}")
-    grid = (r // br,)
     # Coalescing only changes behavior where the row fetch is real data
     # movement (the registry's coalescable tiers); VMEM-resident stores keep
     # their direct per-replica reads so the flag never perturbs their layout.
     coalesce = coalesce and coupling_store.FORMATS[coupling].coalescable
-    in_specs, j_args, scratch_shapes = _STORE_LAYOUTS[coupling](
+    in_specs, j_args, store_scratch, nbytes = _STORE_LAYOUTS[coupling](
         couplings, n, br, coalesce)
-    in_specs = in_specs + [
-        pl.BlockSpec((br, n), lambda i: (i, 0)),       # u0
-        pl.BlockSpec((br, n), lambda i: (i, 0)),       # s0
-        pl.BlockSpec((br, 1), lambda i: (i, 0)),       # e0
-        pl.BlockSpec((t, br, 4), lambda i: (0, i, 0)),  # uniforms
-        pl.BlockSpec((t, br), lambda i: (0, i)),       # temps
-    ]
-    args = j_args + [fields0, spins0, energy0.reshape(r, 1), uniforms, temps]
+    state_in, out_specs = _state_specs(br, n, t, 4)
+    in_specs = in_specs + state_in
+    args = j_args + [fields0, spins0, energy0.reshape(r, 1), uniforms,
+                     temps.reshape(t, r, 1)]
     if pwl_table is not None:
         in_specs.append(pl.BlockSpec(pwl_table.shape, lambda i: (0, 0)))
         args.append(pwl_table)
+    nbytes += _state_vmem_bytes(br, n, t, 4)
     outs = pl.pallas_call(
         functools.partial(_kernel, num_steps=t, mode=mode,
                           uniformized=uniformized, gather=gather, lane=lane,
                           has_pwl=pwl_table is not None, coupling=coupling,
                           coalesce=coalesce),
-        grid=grid,
+        grid=(r // br,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-            pl.BlockSpec((br, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((r, n), jnp.float32),
-            jax.ShapeDtypeStruct((r, n), spins0.dtype),
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-            jax.ShapeDtypeStruct((r, 1), jnp.float32),
-            jax.ShapeDtypeStruct((r, n), spins0.dtype),
-            jax.ShapeDtypeStruct((r, 1), jnp.int32),
-            jax.ShapeDtypeStruct((r, 1), jnp.int32),
-        ],
-        scratch_shapes=scratch_shapes,
+        out_specs=out_specs,
+        out_shape=_out_shapes(r, n, spins0.dtype),
+        scratch_shapes=[pltpu.VMEM((br, n), jnp.float32),
+                        pltpu.VMEM((br, n), jnp.float32)] + store_scratch,
+        compiler_params=_compiler_params(nbytes),
         interpret=interpret,
+        name="mcmc_sweep",
     )(*args)
     u, s, e, be, bs, nf, rf = outs
     return u, s, e[:, 0], be[:, 0], bs, nf[:, 0], rf[:, 0]

@@ -27,6 +27,7 @@ from repro.graphs import (complete_bipolar, erdos_renyi, maxcut_to_ising,
                           parse_gset, small_world, torus_grid)
 from repro.graphs.maxcut import cut_from_energy
 from repro.kernels import fused_anneal
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def build_instance(args):
@@ -118,6 +119,7 @@ def main():
     res.add_argument("--chunk-steps", type=int, default=256,
                      help="snapshot/budget granularity for untraced runs")
     args = ap.parse_args()
+    enable_compile_cache()
 
     inst = build_instance(args)
     problem = maxcut_to_ising(inst)

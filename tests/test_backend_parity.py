@@ -262,8 +262,9 @@ def test_sweep_bitplane_rejects_mismatches():
 
 
 def test_sweep_block_r_clamps_to_divisor():
-    """R=12 with block_r=8 must fall back to the largest divisor (6), not
-    raise — and the clamped run stays trajectory-exact vs the oracle."""
+    """R=12 with block_r=8 has no legal 8-multiple block, so the kernel
+    takes all 12 replicas in one block instead of raising — and the clamped
+    run stays trajectory-exact vs the oracle."""
     r, n, t = 12, 64, 16
     args = _inputs(21, r, n, t)
     got = sweep_kernel(*args, mode="rwa", block_r=8, interpret=True)

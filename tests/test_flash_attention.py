@@ -7,21 +7,6 @@ import pytest
 from repro.kernels.flash_attention import flash_attention
 from repro.models.layers import chunked_attention
 
-# jax 0.4.x's Pallas interpreter cannot discharge this kernel's masked loads
-# (`_load_discharge_rule` hits an AttributeError on integer indexers) — broken
-# since the repo seed, on every test in this module. Keyed on the jax version
-# so an upgrade that fixes the interpreter turns these back into real tests
-# (strict=False: an xpass is reported, not failed) while keeping tier-1 green
-# and real regressions visible today.
-_JAX_VERSION = tuple(int(p) for p in jax.__version__.split(".")[:2])
-pytestmark = pytest.mark.xfail(
-    condition=_JAX_VERSION < (0, 5),
-    reason="pallas interpret-mode _load_discharge_rule AttributeError on "
-           f"jax {jax.__version__} (pre-existing since seed)",
-    strict=False,
-)
-
-
 def _qkv(seed, b, hq, hkv, s, d, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     q = jnp.asarray(rng.normal(size=(b, hq, s, d)), dtype)

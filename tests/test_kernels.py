@@ -170,8 +170,8 @@ def test_sweep_bitplane_hbm_step_has_no_quadratic_contraction():
 
 
 def test_bitplane_field_kernel_clamps_blocks():
-    """Non-dividing block_r/block_n fall back to the largest divisors
-    (R=12/block_r=8 → 6; N=96/block_n=64 → 48) instead of raising."""
+    """Non-dividing block_r/block_n do not raise: R=12/block_r=8 takes all
+    12 replicas in one block, N=96/block_n=64 runs a ragged edge block."""
     rng = np.random.default_rng(4)
     n, b, r = 96, 2, 12
     J = rng.integers(-3, 4, size=(n, n))

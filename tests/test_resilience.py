@@ -331,7 +331,38 @@ def test_is_allocation_failure_classification():
     assert is_allocation_failure(fake_oom())
     assert is_allocation_failure(MemoryError("x"))
     assert is_allocation_failure(RuntimeError("Failed to allocate 8 bytes"))
+    assert is_allocation_failure(RuntimeError("host OOM while staging J"))
     assert not is_allocation_failure(ValueError("J must be symmetric"))
+    # Markers match whole words only.
+    assert not is_allocation_failure(RuntimeError("no room left in the zoom"))
+    assert not is_allocation_failure(RuntimeError("bloomfilter misconfigured"))
+    assert not is_allocation_failure(_MOSAIC_VMEM_REFUSAL)
+
+
+#: The shape of the TPU compiler's refusal of a kernel whose blocks overflow
+#: its scoped VMEM: it names memory, but no coupling tier can fix it.
+_MOSAIC_VMEM_REFUSAL = RuntimeError(
+    "INTERNAL: Mosaic failed to compile TPU kernel: Out of memory while "
+    "trying to allocate 40.00M of scoped vmem; scoped allocation exceeds "
+    "the vmem_limit_bytes of the kernel")
+
+
+def test_mosaic_compile_error_propagates_without_downgrade(problem):
+    """A compile refusal inside an "auto" run is raised as it is — never
+    retried one tier down (which would hide the broken kernel behind a
+    slower, silently chosen store)."""
+    cfg = _cfg("rwa", "auto")
+    events = []
+
+    def refuse(site, info):
+        if site == "chunk_start":
+            raise _MOSAIC_VMEM_REFUSAL
+
+    with inject_faults(refuse):
+        with pytest.raises(RuntimeError, match="Mosaic failed to compile"):
+            run_resilient(problem, 7, cfg,
+                          on_event=lambda k, i: events.append(k))
+    assert "tier_downgrade" not in events
 
 
 def test_next_tier_ladder(problem):

@@ -88,8 +88,13 @@ def test_coalesce_rows_matches_python_oracle():
     for _ in range(200):
         r = int(g.integers(1, 12))
         j = g.integers(0, 7, size=r).astype(np.int32)
-        nu, usite, uo, fetched = jax.jit(common.coalesce_rows)(jnp.asarray(j))
-        nu, usite, uo, fetched = map(np.asarray, (nu, usite, uo, fetched))
+        # The plan takes and returns (R, 1) columns (the kernel's layout).
+        nu, usite, uo, fetched = jax.jit(common.coalesce_rows)(
+            jnp.asarray(j)[:, None])
+        assert usite.shape == uo.shape == fetched.shape == (r, 1)
+        nu, usite, uo, fetched = (np.asarray(x).reshape(-1)
+                                  for x in (nu, usite, uo, fetched))
+        nu = int(nu[0])
         uniq = list(dict.fromkeys(j.tolist()))   # first-occurrence order
         assert nu == len(uniq)
         assert (usite[:nu] == np.array(uniq)).all()
