@@ -215,23 +215,30 @@ def capability_rows() -> list:
 @partial(jax.jit, static_argnames=("config", "interpret"))
 def _fused_init(problem, seed, config: SolverConfig, store: CouplingStore,
                 interpret: bool):
+    """The initial 6-tuple and a zero (R,) rows-fetched sum."""
     from ..kernels import ops as _ops
     base = jax.random.fold_in(jax.random.key(0), seed)
-    return _ops.fused_init_state(problem, base, config.num_replicas,
-                                 interpret=interpret, planes=store.planes)
+    r = config.num_replicas
+    state = _ops.fused_init_state(problem, base, r, interpret=interpret,
+                                  planes=store.planes)
+    return state, jnp.zeros((r,), jnp.int32)
 
 
 @partial(jax.jit, static_argnames=("config", "clen", "chunk_len", "gather",
                                    "interpret"))
-def _fused_chunk(state, seed, c, store: CouplingStore, *,
+def _fused_chunk(state, rows_fetched, seed, c, store: CouplingStore, *,
                  config: SolverConfig, clen: int, chunk_len: int,
                  gather: str, interpret: bool):
+    """One chunk; the running (R,) rows-fetched sum goes in and out, so the
+    counter costs no dispatch of its own."""
     from ..kernels import ops as _ops
     base = jax.random.fold_in(jax.random.key(0), seed)
-    return _ops.anneal_chunk_step(store, state, base, c, clen=clen,
-                                  chunk_len=chunk_len, config=config,
-                                  gather=gather, block_r=8,
-                                  interpret=interpret)
+    state, rf = _ops.anneal_chunk_step(store, state, base, c, clen=clen,
+                                       chunk_len=chunk_len, config=config,
+                                       gather=gather, block_r=8,
+                                       interpret=interpret,
+                                       with_rows_fetched=True)
+    return state, rows_fetched + rf
 
 
 class FusedRunner:
@@ -254,6 +261,7 @@ class FusedRunner:
         self.total_units = self.num_chunks + (1 if self.rem_steps else 0)
         self.collect_trace = bool(config.trace_every)
         self.num_replicas = config.num_replicas
+        self._rows_fetched = None
 
     def unit_len(self, k: int) -> int:
         if self.rem_steps and k == self.num_chunks:
@@ -261,14 +269,23 @@ class FusedRunner:
         return self.chunk_len
 
     def init(self):
-        return _fused_init(self.problem, self.seed, self.config, self.store,
-                           self.interpret)
+        state, self._rows_fetched = _fused_init(
+            self.problem, self.seed, self.config, self.store, self.interpret)
+        return state
 
     def run_chunk(self, state, k: int):
-        return _fused_chunk(state, self.seed, jnp.int32(k), self.store,
-                            config=self.config, clen=self.unit_len(k),
-                            chunk_len=self.chunk_len, gather=self.gather,
-                            interpret=self.interpret)
+        # As in ColoredRunner, the rows-fetched sum rides on the runner: the
+        # 6-tuple snapshot contract stays fixed and the counter covers the
+        # chunks this process ran since its last init (telemetry only).
+        rf = self._rows_fetched
+        if rf is None:      # continuing a state this runner did not init
+            rf = jnp.zeros((self.num_replicas,), jnp.int32)
+        state, self._rows_fetched = _fused_chunk(
+            state, rf, self.seed, jnp.int32(k), self.store,
+            config=self.config, clen=self.unit_len(k),
+            chunk_len=self.chunk_len, gather=self.gather,
+            interpret=self.interpret)
+        return state
 
     def best_energy(self, state) -> float:
         return float(jnp.min(state[3])) + float(self.problem.offset)
@@ -286,7 +303,8 @@ class FusedRunner:
             trace = jnp.zeros((0, r), jnp.float32)
         return SolveResult(best_energy=be + off, best_spins=bs.astype(jnp.int8),
                            final_energy=e + off, num_flips=nf,
-                           trace_energy=trace)
+                           trace_energy=trace,
+                           rows_fetched=self._rows_fetched)
 
 
 @partial(jax.jit, static_argnames=("config", "interpret"))

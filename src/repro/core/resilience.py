@@ -48,6 +48,13 @@ distributed) in one chunk-granular supervisor, :func:`run_resilient`:
   store is per-device by construction; losing a host is handled by replica
   independence, not by re-tiering).
 
+**Spans.** Every solve opens ``jax.profiler.TraceAnnotation`` spans named
+``snowball.*`` (:func:`span`) around its supervisor steps — the whole
+solve, the two identity hashes, each runner build, init, chunk dispatch and
+finalize — tagged with a per-process solve id. Under a profiler session
+they land in the same trace as the device ops, on the same clock; without
+one each costs well under a microsecond.
+
 Fault injection for tests rides on :func:`inject_faults` — a context-local
 hook fired at the supervisor's seams ("store_build", "chunk_start",
 "checkpoint_saved") so the harness (``tests/fault_injection.py``) can raise
@@ -58,6 +65,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import hashlib
+import itertools
 import re
 import time
 from typing import Callable, NamedTuple, Optional
@@ -101,7 +109,20 @@ class ResilientResult(NamedTuple):
     total_chunks: int
     resumed_from_chunk: Optional[int]   # snapshot the run resumed at, or None
     downgrades: tuple           # ((from_fmt, to_fmt, at_chunk), ...)
-    wall_seconds: float
+
+
+# --------------------------------------------------------------------------
+# Spans: the supervisor's steps in the profiler's trace.
+
+SPAN_PREFIX = "snowball."
+_solve_ids = itertools.count()
+
+
+def span(name: str, **ids):
+    """A profiler span ``snowball.<name>`` carrying ``ids`` as its stats.
+    It records only while a profiler session (``jax.profiler.trace``) is
+    active, in the same trace and on the same clock as the device ops."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **ids)
 
 
 # --------------------------------------------------------------------------
@@ -325,108 +346,116 @@ def run_resilient(problem: ising.IsingProblem, seed, config,
     keyed per chunk. ``on_event(kind, info)`` observes "resume",
     "chunk", "snapshot", "snapshot_corrupt", "tier_downgrade", "stop".
     """
-    t_start = time.monotonic()
-    backend = resolve_backend(config, backend, mesh)
-    budget = budget or BudgetConfig()
-    emit = on_event or (lambda kind, info: None)
-    signature = run_signature(problem, seed, config, backend=backend,
-                              chunk_steps=chunk_steps, mesh=mesh)
-    fingerprint = problem_fingerprint(problem)
-    mgr = (ckpt.CheckpointManager(run_dir, keep=keep)
-           if run_dir is not None else None)
-    downgrades: list = []
-    fmt: Optional[str] = None
-    resumed_from: Optional[int] = None
+    sid = next(_solve_ids)
+    with span("solve", solve=sid):
+        t_start = time.monotonic()
+        backend = resolve_backend(config, backend, mesh)
+        budget = budget or BudgetConfig()
+        emit = on_event or (lambda kind, info: None)
+        with span("fingerprint", solve=sid, what="signature"):
+            signature = run_signature(problem, seed, config, backend=backend,
+                                      chunk_steps=chunk_steps, mesh=mesh)
+        with span("fingerprint", solve=sid, what="fingerprint"):
+            fingerprint = problem_fingerprint(problem)
+        mgr = (ckpt.CheckpointManager(run_dir, keep=keep)
+               if run_dir is not None else None)
+        downgrades: list = []
+        fmt: Optional[str] = None
+        resumed_from: Optional[int] = None
 
-    def build(fmt):
-        _fault("store_build",
-               fmt=_current_fmt(problem, config, backend, fmt),
-               backend=backend)
-        return get_backend(backend).runner(
-            problem, seed, config, mesh=mesh, chunk_steps=chunk_steps,
-            fmt=fmt, store=store)
+        def build(fmt):
+            cur = _current_fmt(problem, config, backend, fmt)
+            with span("runner_build", solve=sid, fmt=cur):
+                _fault("store_build", fmt=cur, backend=backend)
+                return get_backend(backend).runner(
+                    problem, seed, config, mesh=mesh,
+                    chunk_steps=chunk_steps, fmt=fmt, store=store)
 
-    def downgrade_or_raise(exc, at_chunk: int):
-        nonlocal fmt
-        if not (_fallback_enabled(config, backend)
-                and is_allocation_failure(exc)):
-            raise exc
-        cur = _current_fmt(problem, config, backend, fmt)
-        nxt = next_tier(cur, problem, mesh)
-        if nxt is None:
-            raise exc
-        downgrades.append((cur, nxt, at_chunk))
-        emit("tier_downgrade", {"from": cur, "to": nxt, "chunk": at_chunk,
-                                "error": str(exc)})
-        fmt = nxt
+        def downgrade_or_raise(exc, at_chunk: int):
+            nonlocal fmt
+            if not (_fallback_enabled(config, backend)
+                    and is_allocation_failure(exc)):
+                raise exc
+            cur = _current_fmt(problem, config, backend, fmt)
+            nxt = next_tier(cur, problem, mesh)
+            if nxt is None:
+                raise exc
+            downgrades.append((cur, nxt, at_chunk))
+            emit("tier_downgrade", {"from": cur, "to": nxt,
+                                    "chunk": at_chunk, "error": str(exc)})
+            fmt = nxt
 
-    runner = None
-    while runner is None:
-        try:
-            runner = build(fmt)
-        except Exception as e:   # noqa: BLE001 — alloc-failure triage
-            downgrade_or_raise(e, 0)
-
-    while True:   # tier-retry loop around the chunk drive
-        state, rows, k, steps_done = None, [], 0, 0
-        try:
-            if mgr is not None and resume:
-                state, rows, k, steps_done, prior = _try_resume(
-                    run_dir, runner, signature, fingerprint, emit)
-                if state is not None:
-                    resumed_from = k
-                    # Downgrades recorded by the pre-crash attempt survive.
-                    downgrades = prior + [d for d in downgrades
-                                          if d not in prior]
-            if state is None:
-                state = runner.init()
-            total = runner.total_units
-            stop_reason = STOP_COMPLETED
+        runner = None
+        while runner is None:
             try:
-                while k < total:
-                    reason = _check_budget(budget, runner, state, steps_done,
-                                           t_start)
-                    if reason is not None:
-                        stop_reason = reason
-                        break
-                    _fault("chunk_start", chunk=k, fmt=runner.fmt)
-                    state = runner.run_chunk(state, k)
-                    steps_done += runner.unit_len(k)
-                    if runner.collect_trace:
-                        rows.append(np.asarray(jax.device_get(
-                            runner.trace_row(state))))
-                    k += 1
-                    emit("chunk", {"chunk": k, "total": total})
-                    if mgr is not None and (k % checkpoint_every == 0
-                                            or k == total):
-                        _save_snapshot(mgr, runner, state, rows, k,
-                                       steps_done, signature, fingerprint,
-                                       downgrades)
-                        emit("snapshot", {"chunk": k})
-                        _fault("checkpoint_saved", chunk=k)
-            except KeyboardInterrupt:
-                stop_reason = STOP_INTERRUPTED
-            if stop_reason != STOP_COMPLETED and mgr is not None and k > 0:
-                # Budget/interrupt stop between snapshots: persist the
-                # frontier so a later run continues instead of replaying.
-                _save_snapshot(mgr, runner, state, rows, k, steps_done,
-                               signature, fingerprint, downgrades)
-            break
-        except Exception as e:   # noqa: BLE001 — alloc-failure triage
-            downgrade_or_raise(e, k)
-            runner = None
-            while runner is None:
-                try:
-                    runner = build(fmt)
-                except Exception as e2:  # noqa: BLE001
-                    downgrade_or_raise(e2, k)
+                runner = build(fmt)
+            except Exception as e:   # noqa: BLE001 — alloc-failure triage
+                downgrade_or_raise(e, 0)
 
-    result = runner.finalize(state, rows)
-    emit("stop", {"reason": stop_reason, "chunks_done": k,
-                  "steps_done": steps_done})
-    return ResilientResult(result=result, stop_reason=stop_reason,
-                           steps_done=steps_done, chunks_done=k,
-                           total_chunks=runner.total_units,
-                           resumed_from_chunk=resumed_from,
-                           downgrades=tuple(downgrades),
-                           wall_seconds=time.monotonic() - t_start)
+        while True:   # tier-retry loop around the chunk drive
+            state, rows, k, steps_done = None, [], 0, 0
+            try:
+                if mgr is not None and resume:
+                    state, rows, k, steps_done, prior = _try_resume(
+                        run_dir, runner, signature, fingerprint, emit)
+                    if state is not None:
+                        resumed_from = k
+                        # Downgrades recorded by the pre-crash attempt
+                        # survive.
+                        downgrades = prior + [d for d in downgrades
+                                              if d not in prior]
+                if state is None:
+                    with span("init", solve=sid):
+                        state = runner.init()
+                total = runner.total_units
+                stop_reason = STOP_COMPLETED
+                try:
+                    while k < total:
+                        reason = _check_budget(budget, runner, state,
+                                               steps_done, t_start)
+                        if reason is not None:
+                            stop_reason = reason
+                            break
+                        _fault("chunk_start", chunk=k, fmt=runner.fmt)
+                        with span("chunk", solve=sid, chunk=k):
+                            state = runner.run_chunk(state, k)
+                        steps_done += runner.unit_len(k)
+                        if runner.collect_trace:
+                            rows.append(np.asarray(jax.device_get(
+                                runner.trace_row(state))))
+                        k += 1
+                        emit("chunk", {"chunk": k, "total": total})
+                        if mgr is not None and (k % checkpoint_every == 0
+                                                or k == total):
+                            _save_snapshot(mgr, runner, state, rows, k,
+                                           steps_done, signature,
+                                           fingerprint, downgrades)
+                            emit("snapshot", {"chunk": k})
+                            _fault("checkpoint_saved", chunk=k)
+                except KeyboardInterrupt:
+                    stop_reason = STOP_INTERRUPTED
+                if (stop_reason != STOP_COMPLETED and mgr is not None
+                        and k > 0):
+                    # Budget/interrupt stop between snapshots: persist the
+                    # frontier so a later run continues instead of replaying.
+                    _save_snapshot(mgr, runner, state, rows, k, steps_done,
+                                   signature, fingerprint, downgrades)
+                break
+            except Exception as e:   # noqa: BLE001 — alloc-failure triage
+                downgrade_or_raise(e, k)
+                runner = None
+                while runner is None:
+                    try:
+                        runner = build(fmt)
+                    except Exception as e2:  # noqa: BLE001
+                        downgrade_or_raise(e2, k)
+
+        with span("finalize", solve=sid):
+            result = runner.finalize(state, rows)
+        emit("stop", {"reason": stop_reason, "chunks_done": k,
+                      "steps_done": steps_done})
+        return ResilientResult(result=result, stop_reason=stop_reason,
+                               steps_done=steps_done, chunks_done=k,
+                               total_chunks=runner.total_units,
+                               resumed_from_chunk=resumed_from,
+                               downgrades=tuple(downgrades))

@@ -239,8 +239,9 @@ def anneal_chunk_step(store: CouplingStore, state, base: jax.Array,
     resilient supervisor's per-chunk jit (``core.resilience``) — one
     definition is what makes the resumed trajectory bit-identical to the
     uninterrupted scan. ``with_rows_fetched`` surfaces the kernel's
-    rows-fetched counter as a second return (the resilient path keeps the
-    bare 6-tuple — its snapshot contract)."""
+    rows-fetched counter as a second return (the state stays the bare
+    6-tuple — the snapshot contract; the resilient runner carries the sum
+    beside it)."""
     r = config.num_replicas
     steps = c * chunk_len + jnp.arange(clen)
     temps = jax.vmap(config.schedule)(steps).astype(jnp.float32)

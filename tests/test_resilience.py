@@ -2,9 +2,12 @@
 corrupt-snapshot recovery, budgets, and tier fallback (in-process tiers;
 the spin-sharded tier's kill-and-resume runs on a forced mesh in
 ``test_fault_injection.py``)."""
+import glob
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import ising, schedules
@@ -12,9 +15,9 @@ from repro.core.solver import SolverConfig, solve
 from repro.core.tempering import TemperingConfig, solve_tempering
 from repro.core.resilience import (BudgetConfig, run_resilient,
                                    inject_faults, is_allocation_failure,
-                                   next_tier, STOP_COMPLETED, STOP_DEADLINE,
-                                   STOP_INTERRUPTED, STOP_MAX_STEPS,
-                                   STOP_TARGET)
+                                   next_tier, SPAN_PREFIX, STOP_COMPLETED,
+                                   STOP_DEADLINE, STOP_INTERRUPTED,
+                                   STOP_MAX_STEPS, STOP_TARGET)
 from repro.checkpoint import snapshot_steps
 
 from fault_injection import (SimulatedCrash, corrupt_snapshot, fake_oom,
@@ -131,6 +134,93 @@ def test_untraced_run_covers_remainder_chunk(problem):
     res = run_resilient(problem, 7, cfg, chunk_steps=50)
     assert res.total_chunks == 3 and res.steps_done == STEPS
     _assert_same_solve(mono, res.result)
+
+
+@pytest.mark.parametrize("fmt", ["dense", "bitplane_hbm"])
+def test_fused_rows_fetched_matches_fused_anneal(problem, fmt):
+    # The fused runner carries the kernel's rows-fetched counter across the
+    # chunks (50, 50 and a 20-step tail), beside an unchanged trajectory.
+    from repro.kernels.ops import fused_anneal
+    cfg = SolverConfig(num_steps=STEPS,
+                       schedule=schedules.linear(3.0, 0.1, STEPS),
+                       num_replicas=REPLICAS, coupling_format=fmt)
+    mono = fused_anneal(problem, 7, cfg, chunk_steps=50)
+    res = run_resilient(problem, 7, cfg, backend="fused",
+                        chunk_steps=50).result
+    _assert_same_solve(mono, res)
+    np.testing.assert_array_equal(np.asarray(mono.rows_fetched),
+                                  np.asarray(res.rows_fetched))
+    assert np.asarray(res.rows_fetched).sum() > 0
+
+
+# ---------------------------------------------------------------- spans
+
+SPANS = ("solve", "fingerprint", "runner_build", "init", "chunk", "finalize")
+
+
+def _profiled(log_dir, fn):
+    """Run ``fn`` under a profiler session. Returns, from the one xplane
+    file it wrote, the program's spans as ``(name, start, end, ids)`` with
+    the prefix dropped, and the ``(start, end)`` of the XLA:CPU op events."""
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    spans, ops = [], []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                a, b = e.start_ns, e.start_ns + e.duration_ns
+                if e.name.startswith(SPAN_PREFIX):
+                    spans.append((e.name[len(SPAN_PREFIX):], a, b,
+                                  dict(e.stats)))
+                elif (plane.name == "/host:CPU"
+                      and line.name.startswith("tf_XLA")):
+                    ops.append((a, b))
+    return spans, ops
+
+
+def test_spans_of_two_solves_nest_under_their_solve_id(problem, tmp_path):
+    cfg = SolverConfig(num_steps=STEPS,
+                       schedule=schedules.linear(3.0, 0.1, STEPS),
+                       num_replicas=REPLICAS, coupling_format="dense")
+
+    def solve_once(seed):
+        rr = run_resilient(problem, seed, cfg, backend="fused",
+                           chunk_steps=STEPS // 2)
+        jax.block_until_ready(rr.result.best_energy)
+        return rr
+
+    solve_once(6)       # compiles outside the profiled window
+    results = []
+    spans, ops = _profiled(tmp_path, lambda: results.extend(
+        solve_once(seed) for seed in (7, 8)))
+
+    assert {s[0] for s in spans} == set(SPANS)
+    solves = sorted((s for s in spans if s[0] == "solve"),
+                    key=lambda s: s[1])
+    assert len(solves) == 2
+    assert solves[0][3]["solve"] != solves[1][3]["solve"]
+    for (_, lo, hi, ids), rr in zip(solves, results):
+        kids = sorted((s for s in spans
+                       if s[0] != "solve" and s[3]["solve"] == ids["solve"]),
+                      key=lambda s: s[1])
+        assert all(lo <= a <= b <= hi for _, a, b, _ in kids)
+        assert [s[0] for s in kids] == ["fingerprint", "fingerprint",
+                                        "runner_build", "init", "chunk",
+                                        "chunk", "finalize"]
+        assert [s[3]["what"] for s in kids[:2]] == ["signature",
+                                                    "fingerprint"]
+        assert kids[2][3]["fmt"] == "dense"
+        assert rr.total_chunks == 2
+        assert [s[3]["chunk"] for s in kids if s[0] == "chunk"] == \
+            list(range(rr.total_chunks))
+    # One file, one clock: the XLA ops the solves dispatched start inside
+    # their solve spans.
+    assert ops
+    assert any(lo <= a < hi for a, _ in ops for _, lo, hi, _ in solves)
 
 
 # ---------------------------------------------------------------- resume
