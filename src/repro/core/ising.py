@@ -208,6 +208,24 @@ class IsingProblem:
         return self.edges.num_spins
 
     @property
+    def immutable(self) -> bool:
+        """Whether this object's content can never change: ``h`` and a dense
+        ``J`` are ``jax.Array``s (what :meth:`create` and
+        :meth:`create_sparse` build), or the couplings are an
+        :class:`EdgeList`, whose digest is cached on first use. A problem
+        built by hand over NumPy arrays can change in place."""
+        return isinstance(self.fields, jax.Array) and (
+            isinstance(self.couplings, jax.Array)
+            or (self.couplings is None and self.edges is not None))
+
+    @cached_property
+    def _fingerprint(self) -> str:
+        """:func:`content_fingerprint` of this object, computed once. Read
+        only where :attr:`immutable` holds
+        (``core.resilience.problem_fingerprint``)."""
+        return content_fingerprint(self)
+
+    @property
     def coupling_source(self):
         """What ``core.coupling.CouplingStore.build`` consumes: the edge list
         when the problem is dense-J-free, else the dense J."""
@@ -261,6 +279,26 @@ class IsingProblem:
             raise ValueError(f"h shape {h.shape} incompatible with N={n}")
         return cls(couplings=None, fields=jnp.asarray(h), offset=float(offset),
                    edges=edges)
+
+
+def content_fingerprint(problem: IsingProblem) -> str:
+    """sha256 hex digest of a problem's content: the dense J's shape and
+    bytes (or the edge list's digest), then h's bytes and the offset as a
+    float64. Hashes on every call; ``core.resilience.problem_fingerprint``
+    is the entry point that reuses the value per immutable object."""
+    h = hashlib.sha256()
+    if problem.couplings is not None:
+        J = np.ascontiguousarray(jax.device_get(problem.couplings))
+        h.update(b"dense")
+        h.update(repr(J.shape).encode())
+        h.update(J.tobytes())
+    else:
+        h.update(b"edges")
+        h.update(problem.edges._digest)
+    fields = np.ascontiguousarray(jax.device_get(problem.fields))
+    h.update(fields.tobytes())
+    h.update(np.float64(problem.offset).tobytes())
+    return h.hexdigest()
 
 
 def _require_dense(problem: IsingProblem, what: str) -> jax.Array:

@@ -50,10 +50,12 @@ distributed) in one chunk-granular supervisor, :func:`run_resilient`:
 
 **Spans.** Every solve opens ``jax.profiler.TraceAnnotation`` spans named
 ``snowball.*`` (:func:`span`) around its supervisor steps — the whole
-solve, the two identity hashes, each runner build, init, chunk dispatch and
-finalize — tagged with a per-process solve id. Under a profiler session
-they land in the same trace as the device ops, on the same clock; without
-one each costs well under a microsecond.
+solve, the two identity steps (``cached`` = 1 where the problem's
+fingerprint was kept from an earlier solve and no byte is hashed), each
+runner build, init, chunk dispatch and finalize — tagged with a
+per-process solve id. Under a profiler session they land in the same trace
+as the device ops, on the same clock; without one each costs well under a
+microsecond.
 
 Fault injection for tests rides on :func:`inject_faults` — a context-local
 hook fired at the supervisor's seams ("store_build", "chunk_start",
@@ -210,27 +212,32 @@ def next_tier(fmt: str, problem: ising.IsingProblem, mesh) -> Optional[str]:
 def problem_fingerprint(problem: ising.IsingProblem) -> str:
     """Content hash of the problem (couplings/edges + fields + offset) —
     written into every snapshot so a resume onto a different instance is
-    refused instead of silently mixing trajectories."""
-    h = hashlib.sha256()
-    if problem.couplings is not None:
-        J = np.ascontiguousarray(jax.device_get(problem.couplings))
-        h.update(b"dense")
-        h.update(repr(J.shape).encode())
-        h.update(J.tobytes())
-    else:
-        h.update(b"edges")
-        h.update(problem.edges._digest)
-    fields = np.ascontiguousarray(jax.device_get(problem.fields))
-    h.update(fields.tobytes())
-    h.update(np.float64(problem.offset).tobytes())
-    return h.hexdigest()
+    refused instead of silently mixing trajectories.
+
+    The hash (``ising.content_fingerprint``) runs once per problem object
+    and is kept on it when the content cannot change under it
+    (``IsingProblem.immutable``: ``jax.Array`` J and h, as
+    ``IsingProblem.create`` builds, or an ``EdgeList``), so the repeat
+    solves of one instance hash nothing. A problem built by hand over NumPy
+    arrays, which can change in place, is hashed on every call."""
+    if problem.immutable:
+        return problem._fingerprint
+    return ising.content_fingerprint(problem)
+
+
+def fingerprint_cached(problem: ising.IsingProblem) -> bool:
+    """Whether :func:`problem_fingerprint` will return a kept value and
+    hash no bytes of ``problem``."""
+    return problem.immutable and "_fingerprint" in vars(problem)
 
 
 def run_signature(problem: ising.IsingProblem, seed, config, *, backend: str,
                   chunk_steps: int, mesh) -> str:
     """Hash of everything the chunk cadence and RNG streams depend on. The
     configs are frozen dataclasses of plain values, so their reprs are
-    stable across processes."""
+    stable across processes. The problem enters through
+    :func:`problem_fingerprint`, so an immutable problem's bytes are hashed
+    only on its first solve; a NumPy-backed one is hashed on every call."""
     mesh_desc = (None if mesh is None
                  else tuple((a, int(mesh.shape[a])) for a in mesh.axis_names))
     parts = "|".join([
@@ -352,10 +359,12 @@ def run_resilient(problem: ising.IsingProblem, seed, config,
         backend = resolve_backend(config, backend, mesh)
         budget = budget or BudgetConfig()
         emit = on_event or (lambda kind, info: None)
-        with span("fingerprint", solve=sid, what="signature"):
+        cached = int(fingerprint_cached(problem))
+        with span("fingerprint", solve=sid, what="signature", cached=cached):
             signature = run_signature(problem, seed, config, backend=backend,
                                       chunk_steps=chunk_steps, mesh=mesh)
-        with span("fingerprint", solve=sid, what="fingerprint"):
+        with span("fingerprint", solve=sid, what="fingerprint",
+                  cached=cached):
             fingerprint = problem_fingerprint(problem)
         mgr = (ckpt.CheckpointManager(run_dir, keep=keep)
                if run_dir is not None else None)

@@ -194,6 +194,10 @@ def test_spans_of_two_solves_nest_under_their_solve_id(problem, tmp_path):
         return rr
 
     solve_once(6)       # compiles outside the profiled window
+    # A new object with the same content: its first solve hashes it, the
+    # second finds the fingerprint kept on the object.
+    problem = ising.IsingProblem(problem.couplings, problem.fields,
+                                 problem.offset)
     results = []
     spans, ops = _profiled(tmp_path, lambda: results.extend(
         solve_once(seed) for seed in (7, 8)))
@@ -203,7 +207,7 @@ def test_spans_of_two_solves_nest_under_their_solve_id(problem, tmp_path):
                     key=lambda s: s[1])
     assert len(solves) == 2
     assert solves[0][3]["solve"] != solves[1][3]["solve"]
-    for (_, lo, hi, ids), rr in zip(solves, results):
+    for i, ((_, lo, hi, ids), rr) in enumerate(zip(solves, results)):
         kids = sorted((s for s in spans
                        if s[0] != "solve" and s[3]["solve"] == ids["solve"]),
                       key=lambda s: s[1])
@@ -213,6 +217,7 @@ def test_spans_of_two_solves_nest_under_their_solve_id(problem, tmp_path):
                                         "chunk", "finalize"]
         assert [s[3]["what"] for s in kids[:2]] == ["signature",
                                                     "fingerprint"]
+        assert [s[3]["cached"] for s in kids[:2]] == [min(i, 1)] * 2
         assert kids[2][3]["fmt"] == "dense"
         assert rr.total_chunks == 2
         assert [s[3]["chunk"] for s in kids if s[0] == "chunk"] == \
