@@ -14,9 +14,12 @@ backend registry, as ``python -m repro.launch.solve`` does.
     recomputed from the best spins. The best cut is printed beside the
     33,000 target; the cut informs, it does not gate.
 (b) One 256-step chunk of the compiled sweep kernel against the jnp oracle
-    ``kernels.ref.mcmc_sweep`` on identical K2000 inputs, both modes:
-    "exact", or the first step at which the two differ (reported, not
-    gated — the check in (a) is the gate).
+    ``kernels.ref.mcmc_sweep`` on identical inputs, both modes: K2000 on
+    the dense tier, and G62 (the 70×100 torus of ``chipbench/configs/
+    g62.json``) on the VMEM bit-plane tier, whose chunk is also run on the
+    HBM-streamed tier at the same seed. "exact" / "identical", or the
+    first step at which the two differ (reported, not gated — the check in
+    (a) is the gate).
 (c) The other single-device tiers: a G61-sized sparse ±1 Erdős–Rényi graph
     (N=7000, |E|≈17148, VMEM bit-planes), a sparse N=16384 graph (64 MiB of
     planes streamed from HBM), and colored block-Gibbs sweeps on an 84×84
@@ -132,9 +135,10 @@ def phase_k2000(inst, problem, steps: int = K2000_STEPS,
               f"target_cut={target:.0f} energy_check=exact", flush=True)
 
 
-def _sweep_inputs(problem, mode: str, steps: int, interpret: bool):
-    """Identical kernel/oracle operands: the fused init and the first
-    chunk's uniforms and temperatures of a K2000 anneal."""
+def _sweep_inputs(problem, store, mode: str, steps: int, interpret: bool):
+    """Identical kernel/oracle operands: the store's coupling operand, the
+    fused init and the first chunk's uniforms and temperatures of an
+    anneal."""
     import jax
     import jax.numpy as jnp
     from repro.configs.snowball import default_solver
@@ -145,12 +149,13 @@ def _sweep_inputs(problem, mode: str, steps: int, interpret: bool):
                          num_replicas=REPLICAS)
     base = jax.random.fold_in(jax.random.key(0), jnp.uint32(SEED))
     u, s, e = ops.fused_init_state(problem, base, REPLICAS,
-                                   interpret=interpret)[:3]
+                                   interpret=interpret,
+                                   planes=store.planes)[:3]
     unif = rng.uniform01(rng.stream(base, rng.Salt.SWEEP, 0),
                          (steps, REPLICAS, 4))
     temps = jnp.broadcast_to(jax.vmap(cfg.schedule)(jnp.arange(steps))
                              .astype(jnp.float32)[:, None], (steps, REPLICAS))
-    return problem.couplings, u, s, e, unif, temps
+    return store.kernel_operand, u, s, e, unif, temps
 
 
 def _first_difference(kernel, oracle, j, u, s, e, unif, temps):
@@ -168,27 +173,48 @@ def _first_difference(kernel, oracle, j, u, s, e, unif, temps):
     return None, 0.0
 
 
-def phase_oracle(problem, steps: int = ORACLE_STEPS):
+def _verdict(kernel, other, args, same_word: str) -> str:
+    got, want = kernel(*args), other(*args)
+    if all(np.array_equal(np.asarray(a), np.asarray(b))
+           for a, b in zip(got[:6], want[:6])):
+        return same_word
+    t, du = _first_difference(kernel, other, *args)
+    return f"differ first_step={t} max_abs_field_diff={du:g}"
+
+
+def phase_oracle(name: str, problem, steps: int = ORACLE_STEPS,
+                 also: tuple = ()):
+    """The kernel on the tier "auto" resolves against the oracle, and the
+    kernel on each tier of ``also`` against that first kernel, at the same
+    seed (the tiers' trajectory-parity contract)."""
     import jax
+    from repro.core.coupling import CouplingStore
     from repro.kernels import ops, ref, sweep
 
     interpret = ops.auto_interpret(None)
     require_compiled(interpret, "the sweep kernel")
+    store = CouplingStore.build(problem.coupling_source, "auto")
+
+    def kernel_on(st, mode):
+        """The kernel on ``st``'s tier, fed ``st``'s own coupling operand in
+        place of the shared one."""
+        run = jax.jit(lambda *a: sweep.mcmc_sweep(
+            *a, mode=mode, coupling=st.fmt, interpret=interpret))
+        return lambda _, *state: run(st.kernel_operand, *state)
+
     for mode in ("rwa", "rsa"):
-        args = _sweep_inputs(problem, mode, steps, interpret)
-        kernel = jax.jit(lambda *a, mode=mode: sweep.mcmc_sweep(
-            *a, mode=mode, interpret=interpret))
+        args = _sweep_inputs(problem, store, mode, steps, interpret)
+        kernel = kernel_on(store, mode)
         oracle = jax.jit(lambda *a, mode=mode: ref.mcmc_sweep(*a, mode=mode))
-        got, want = kernel(*args), oracle(*args)
-        same = all(np.array_equal(np.asarray(a), np.asarray(b))
-                   for a, b in zip(got[:6], want[:6]))
-        if same:
-            verdict = "exact"
-        else:
-            t, du = _first_difference(kernel, oracle, *args)
-            verdict = f"differ first_step={t} max_abs_field_diff={du:g}"
-        print(f"[b] K{problem.num_spins} {mode}: kernel vs ref.mcmc_sweep "
-              f"over {steps} steps: {verdict}", flush=True)
+        print(f"[b] {name} {mode}: {store.fmt} kernel vs ref.mcmc_sweep over "
+              f"{steps} steps: {_verdict(kernel, oracle, args, 'exact')}",
+              flush=True)
+        for fmt in also:
+            other = kernel_on(CouplingStore.build(problem.coupling_source,
+                                                  fmt), mode)
+            print(f"[b] {name} {mode}: {fmt} kernel vs {store.fmt} kernel "
+                  f"over {steps} steps: "
+                  f"{_verdict(other, kernel, args, 'identical')}", flush=True)
 
 
 def sparse_problem(n: int, num_edges: int, seed: int):
@@ -289,14 +315,16 @@ def main(argv=None) -> int:
         else:
             inst, k2000 = k2000_problem()
             phase_k2000(inst, k2000)
-            phase_oracle(k2000)
+            phase_oracle(f"K{k2000.num_spins}", k2000)
+            from repro.graphs import maxcut_edges_to_ising
+            from repro.graphs.generators import torus_grid_edges
+            phase_oracle("G62", maxcut_edges_to_ising(
+                torus_grid_edges(70, 100, seed=62)), also=("bitplane_hbm",))
             phase_tier("G61-sized ER", sparse_problem(7000, 17148, seed=61),
                        "bitplane")
             phase_tier("sparse N=16384", sparse_problem(16384, 49152,
                                                         seed=16384),
                        "bitplane_hbm")
-            from repro.graphs import maxcut_edges_to_ising
-            from repro.graphs.generators import torus_grid_edges
             phase_tier("torus 84x84", maxcut_edges_to_ising(
                 torus_grid_edges(84, 84, seed=62)), "bitplane",
                 steps=COLORED_STEPS, colored=True)

@@ -224,6 +224,10 @@ def _fused_init(problem, seed, config: SolverConfig, store: CouplingStore,
     return state, jnp.zeros((r,), jnp.int32)
 
 
+#: Replica block of the fused runner's sweep (``kernels.sweep.mcmc_sweep``).
+FUSED_BLOCK_R = 8
+
+
 @partial(jax.jit, static_argnames=("config", "clen", "chunk_len", "gather",
                                    "interpret"))
 def _fused_chunk(state, rows_fetched, seed, c, store: CouplingStore, *,
@@ -235,7 +239,8 @@ def _fused_chunk(state, rows_fetched, seed, c, store: CouplingStore, *,
     base = jax.random.fold_in(jax.random.key(0), seed)
     state, rf = _ops.anneal_chunk_step(store, state, base, c, clen=clen,
                                        chunk_len=chunk_len, config=config,
-                                       gather=gather, block_r=8,
+                                       gather=gather,
+                                       block_r=FUSED_BLOCK_R,
                                        interpret=interpret,
                                        with_rows_fetched=True)
     return state, rows_fetched + rf
@@ -286,6 +291,15 @@ class FusedRunner:
             chunk_len=self.chunk_len, gather=self.gather,
             interpret=self.interpret)
         return state
+
+    def vmem_bytes(self) -> int:
+        """The scoped-VMEM request of a full-length chunk's ``mcmc_sweep``
+        (``kernels.sweep.sweep_vmem_limit``), at ``_fused_chunk``'s block."""
+        from ..kernels import sweep as _sweep
+        return _sweep.sweep_vmem_limit(
+            self.store.kernel_operand, self.num_replicas,
+            self.problem.num_spins, self.chunk_len, coupling=self.fmt,
+            block_r=FUSED_BLOCK_R)
 
     def best_energy(self, state) -> float:
         return float(jnp.min(state[3])) + float(self.problem.offset)

@@ -52,10 +52,11 @@ distributed) in one chunk-granular supervisor, :func:`run_resilient`:
 ``snowball.*`` (:func:`span`) around its supervisor steps — the whole
 solve, the two identity steps (``cached`` = 1 where the problem's
 fingerprint was kept from an earlier solve and no byte is hashed), each
-runner build, init, chunk dispatch and finalize — tagged with a
-per-process solve id. Under a profiler session they land in the same trace
-as the device ops, on the same clock; without one each costs well under a
-microsecond.
+runner build (``fmt``, and for the fused runner ``vmem_bytes``, the
+scoped-VMEM request of its sweep kernel), init, chunk dispatch and
+finalize — tagged with a per-process solve id. Under a profiler session
+they land in the same trace as the device ops, on the same clock; without
+one each costs well under a microsecond.
 
 Fault injection for tests rides on :func:`inject_faults` — a context-local
 hook fired at the supervisor's seams ("store_build", "chunk_start",
@@ -374,11 +375,14 @@ def run_resilient(problem: ising.IsingProblem, seed, config,
 
         def build(fmt):
             cur = _current_fmt(problem, config, backend, fmt)
-            with span("runner_build", solve=sid, fmt=cur):
+            with span("runner_build", solve=sid, fmt=cur) as sp:
                 _fault("store_build", fmt=cur, backend=backend)
-                return get_backend(backend).runner(
+                runner = get_backend(backend).runner(
                     problem, seed, config, mesh=mesh,
                     chunk_steps=chunk_steps, fmt=fmt, store=store)
+                if sp.is_enabled() and hasattr(runner, "vmem_bytes"):
+                    sp.set_metadata(vmem_bytes=runner.vmem_bytes())
+                return runner
 
         def downgrade_or_raise(exc, at_chunk: int):
             nonlocal fmt

@@ -480,11 +480,34 @@ def _out_shapes(r: int, n: int, spin_dtype):
     ]
 
 
-def _compiler_params(nbytes: int):
+def _compiler_params(vmem_limit_bytes: int):
     """Replica blocks are independent ("parallel"); the scoped-VMEM request
     is derived from the kernel's own buffers (``common.vmem_limit``)."""
     return pltpu.CompilerParams(dimension_semantics=("parallel",),
-                                vmem_limit_bytes=common.vmem_limit(nbytes))
+                                vmem_limit_bytes=vmem_limit_bytes)
+
+
+def sweep_vmem_limit(couplings, r: int, n: int, t: int, *, coupling: str,
+                     block_r: int = 8, coalesce: bool = True) -> int:
+    """The scoped-VMEM request (``vmem_limit_bytes``) of the
+    :func:`mcmc_sweep` ``pallas_call`` for R replicas of N spins over T
+    steps: the store layout's bytes plus the state blocks', through
+    ``common.vmem_limit``. It reads the coupling operand's shapes only
+    (the layout runs under ``jax.eval_shape``: nothing is padded or
+    copied), once per shape: the supervisor asks at every runner build."""
+    shapes = jax.tree.map(lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                          couplings)
+    coalesce = coalesce and coupling_store.FORMATS[coupling].coalescable
+    return _sweep_vmem_limit(shapes, r, n, t, coupling,
+                             common.replica_block(r, block_r), coalesce)
+
+
+@functools.lru_cache(maxsize=64)
+def _sweep_vmem_limit(shapes, r, n, t, coupling, br, coalesce) -> int:
+    store = []
+    jax.eval_shape(lambda c: store.append(
+        _STORE_LAYOUTS[coupling](c, n, br, coalesce)[3]), shapes)
+    return common.vmem_limit(store[0] + _state_vmem_bytes(br, n, t, 4))
 
 
 @functools.partial(jax.jit, static_argnames=("coupling", "block_r",
@@ -549,7 +572,7 @@ def colored_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
         out_shape=_out_shapes(r, n_pad, spins0.dtype),
         scratch_shapes=[pltpu.VMEM((br, n_pad), jnp.float32),
                         pltpu.VMEM((br, n_pad), jnp.float32)] + store_scratch,
-        compiler_params=_compiler_params(nbytes),
+        compiler_params=_compiler_params(common.vmem_limit(nbytes)),
         interpret=interpret,
         name="colored_sweep",
     )(*args)
@@ -609,7 +632,7 @@ def mcmc_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
     # movement (the registry's coalescable tiers); VMEM-resident stores keep
     # their direct per-replica reads so the flag never perturbs their layout.
     coalesce = coalesce and coupling_store.FORMATS[coupling].coalescable
-    in_specs, j_args, store_scratch, nbytes = _STORE_LAYOUTS[coupling](
+    in_specs, j_args, store_scratch, _ = _STORE_LAYOUTS[coupling](
         couplings, n, br, coalesce)
     state_in, out_specs = _state_specs(br, n, t, 4)
     in_specs = in_specs + state_in
@@ -618,7 +641,6 @@ def mcmc_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
     if pwl_table is not None:
         in_specs.append(pl.BlockSpec(pwl_table.shape, lambda i: (0, 0)))
         args.append(pwl_table)
-    nbytes += _state_vmem_bytes(br, n, t, 4)
     outs = pl.pallas_call(
         functools.partial(_kernel, num_steps=t, mode=mode,
                           uniformized=uniformized, gather=gather, lane=lane,
@@ -630,7 +652,9 @@ def mcmc_sweep(couplings, fields0: jax.Array, spins0: jax.Array,
         out_shape=_out_shapes(r, n, spins0.dtype),
         scratch_shapes=[pltpu.VMEM((br, n), jnp.float32),
                         pltpu.VMEM((br, n), jnp.float32)] + store_scratch,
-        compiler_params=_compiler_params(nbytes),
+        compiler_params=_compiler_params(sweep_vmem_limit(
+            couplings, r, n, t, coupling=coupling, block_r=block_r,
+            coalesce=coalesce)),
         interpret=interpret,
         name="mcmc_sweep",
     )(*args)

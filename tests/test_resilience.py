@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import ising, schedules
+from repro.core.coupling import CouplingStore
 from repro.core.solver import SolverConfig, solve
 from repro.core.tempering import TemperingConfig, solve_tempering
 from repro.core.resilience import (BudgetConfig, run_resilient,
@@ -19,6 +20,7 @@ from repro.core.resilience import (BudgetConfig, run_resilient,
                                    STOP_DEADLINE, STOP_INTERRUPTED,
                                    STOP_MAX_STEPS, STOP_TARGET)
 from repro.checkpoint import snapshot_steps
+from repro.kernels import sweep
 
 from fault_injection import (SimulatedCrash, corrupt_snapshot, fake_oom,
                              kill_after_chunk_hook, oom_once_hook)
@@ -226,6 +228,20 @@ def test_spans_of_two_solves_nest_under_their_solve_id(problem, tmp_path):
     # their solve spans.
     assert ops
     assert any(lo <= a < hi for a, _ in ops for _, lo, hi, _ in solves)
+
+
+@pytest.mark.parametrize("fmt", FUSED_FMTS)
+def test_runner_build_span_carries_the_sweeps_vmem_request(problem, tmp_path,
+                                                           fmt):
+    cfg = _cfg("rwa", fmt)
+    spans, _ = _profiled(tmp_path, lambda: jax.block_until_ready(
+        run_resilient(problem, 7, cfg, backend="fused").result.best_energy))
+    build, = (s for s in spans if s[0] == "runner_build")
+    store = CouplingStore.build(problem.couplings, fmt)
+    want = sweep.sweep_vmem_limit(store.kernel_operand, REPLICAS, N, TRACE,
+                                  coupling=fmt)
+    assert build[3]["fmt"] == fmt
+    assert build[3]["vmem_bytes"] == want >= 32 * 2**20
 
 
 # ---------------------------------------------------------------- resume

@@ -66,6 +66,7 @@ SWEEPS = {
     "dense-2000-rwa-r16": ("dense", 2000, 16, "rwa", False),
     "bitplane-8000-rsa": ("bitplane", 8000, 8, "rsa", False),
     "bitplane-7000-rwa": ("bitplane", 7000, 8, "rwa", False),
+    "bitplane-7000-rwa-pwl": ("bitplane", 7000, 8, "rwa", True),   # g62.rwa
     "hbm-16384-rsa": ("bitplane_hbm", 16384, 8, "rsa", False),
     "hbm-16384-rwa": ("bitplane_hbm", 16384, 8, "rwa", False),
 }
