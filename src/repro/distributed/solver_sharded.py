@@ -191,7 +191,7 @@ def _sharded_roulette(p_loc, u_roulette, lane, g0, axes):
     g, residual, total, degenerate = common.roulette_block_pick(
         blk, u_roulette[:, None])
     sel = jax.lax.psum(common.select_block(p_loc, g, lane, g0), axes)
-    l = common.roulette_lane_pick(sel, residual, lane)
+    l = common.roulette_lane_pick(sel, residual)
     return ((g * lane + l)[:, 0].astype(jnp.int32), total[:, 0],
             degenerate[:, 0])
 

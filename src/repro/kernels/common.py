@@ -12,7 +12,7 @@ Everything here is also written so that Mosaic (the TPU Pallas compiler) can
 lower it inside the kernel: per-replica quantities are (R, 1) columns, values
 are moved by static lane slices and masked reductions (never by value-level
 ``dynamic_slice`` or ``gather``), and the roulette's prefix sums are a
-sequential masked loop in place of ``cumsum``.
+log-depth doubling scan of shifts and adds in place of ``cumsum``.
 """
 from __future__ import annotations
 
@@ -203,20 +203,36 @@ def take_lane(x: jax.Array, k) -> jax.Array:
 
 
 def prefix_sum(x: jax.Array) -> jax.Array:
-    """Inclusive prefix sum along axis 1 of an (R, K) array, accumulated
-    strictly left to right (((x₀ + x₁) + x₂) + …). The order is fixed, so
-    every backend — XLA CPU, XLA TPU, Mosaic — produces the same bits; the
-    roulette's kernel and oracle share it. It stands in for ``cumsum``,
-    which Mosaic does not lower."""
-    lanes = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    """Inclusive prefix sum along axis 1 of an (R, K) array, by a log-depth
+    doubling scan (Hillis–Steele): at level k every lane adds the lane 2^k
+    to its left (zeros shifted in past lane 0), ⌈log₂ K⌉ levels statically
+    unrolled. The association is fixed by the levels — not left to right,
+    so a prefix can sit an ulp below its left neighbour even for
+    non-negative ``x`` (the picks below take the first crossing, which does
+    not need monotone prefixes). Every backend — XLA CPU, XLA TPU, Mosaic —
+    adds in this order and produces the same bits; the roulette's kernel
+    and oracle share it. It stands in for ``cumsum``, which Mosaic does not
+    lower."""
+    r, width = x.shape
+    step = 1
+    while step < width:
+        left = jnp.concatenate([jnp.zeros((r, step), x.dtype),
+                                x[:, :width - step]], axis=1)
+        x = x + left
+        step *= 2
+    return x
 
-    def body(k, carry):
-        acc, out = carry
-        acc = acc + take_lane(x, k)
-        return acc, jnp.where(lanes == k, acc, out)
 
-    init = (jnp.zeros((x.shape[0], 1), x.dtype), jnp.zeros_like(x))
-    return jax.lax.fori_loop(0, x.shape[1], body, init)[1]
+def first_crossing(c: jax.Array, radius: jax.Array) -> jax.Array:
+    """(R, 1) index of the first lane of the (R, K) prefixes ``c`` that
+    exceeds ``radius``, clamped to K − 1 (no crossing: W ≤ radius by
+    rounding, or a NaN wheel). A masked lane-min, branch-free; it equals
+    the ≤-count ``Σ(c ≤ radius)`` wherever ``c`` is monotone, and every
+    lane before it has ``c ≤ radius``, so the residual into it is ≥ 0."""
+    num = c.shape[1]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
+    return jnp.minimum(jnp.min(jnp.where(c > radius, lanes, num), axis=1,
+                               keepdims=True), num - 1)
 
 
 def block_sums(p: jax.Array, lane: int) -> jax.Array:
@@ -269,25 +285,20 @@ def roulette_block_pick(blk: jax.Array, u_roulette: jax.Array):
     stay exactly equal (the parity contract of this module's docstring).
     """
     num_blocks = blk.shape[1]
-    cb = prefix_sum(blk)                           # (R, G) short scan
+    cb = prefix_sum(blk)                           # (R, G) log-depth scan
     total = cb[:, num_blocks - 1:]                 # W (Eq. 28)
     degenerate = ~((total > 0) & (total < jnp.inf))   # W ≤ 0, inf or NaN
     radius = u_roulette * jnp.where(degenerate, 1.0, total)
-    g = jnp.minimum(
-        jnp.sum((cb <= radius).astype(jnp.int32), axis=1, keepdims=True),
-        num_blocks - 1)                            # block index (R, 1)
+    g = first_crossing(cb, radius)                 # block index (R, 1)
     base = take_lane(cb, g - 1)                   # exclusive prefix; 0 at g=0
     return g, radius - base, total, degenerate
 
 
-def roulette_lane_pick(sel: jax.Array, residual: jax.Array, lane: int):
+def roulette_lane_pick(sel: jax.Array, residual: jax.Array):
     """Level-2 of the hierarchical roulette: the within-block lane pick from
     the (R, lane) selected-block weights (sharded callers psum-combine
     ``sel`` from the block owner; the arithmetic is shared either way)."""
-    cl = prefix_sum(sel)
-    return jnp.minimum(
-        jnp.sum((cl <= residual).astype(jnp.int32), axis=1, keepdims=True),
-        lane - 1)
+    return first_crossing(prefix_sum(sel), residual)
 
 
 def roulette_pick(p_all: jax.Array, u_roulette: jax.Array, lane: int):
@@ -297,12 +308,13 @@ def roulette_pick(p_all: jax.Array, u_roulette: jax.Array, lane: int):
     (R, 1) columns ``(site, total, degenerate)``. Site ``j`` is drawn with
     probability ``p_j / W`` via a two-level scan: a prefix sum over the
     G = N/lane block sums picks the block, a lane-wide prefix sum inside the
-    selected block picks the site — O(G + lane) scan depth instead of O(N).
-    The ≤-count form keeps the pick branch-free.
+    selected block picks the site — O(log G + log lane) scan depth, with no
+    loop. Each level takes the first crossing (:func:`first_crossing`),
+    branch-free.
     """
     blk = block_sums(p_all, lane)                  # (R, G) block weights
     g, residual, total, degenerate = roulette_block_pick(blk, u_roulette)
-    l = roulette_lane_pick(select_block(p_all, g, lane), residual, lane)
+    l = roulette_lane_pick(select_block(p_all, g, lane), residual)
     return (g * lane + l).astype(jnp.int32), total, degenerate
 
 

@@ -67,8 +67,9 @@ Mosaic lowering rules the kernels follow: the sweep state (u, s, best s)
 lives in VMEM refs and is read and written by row (``ref[pl.ds(r, 1), :]``);
 per-replica scalars (site, coefficient) are taken from (br, 1) columns by
 masked reduction; per-step scalars of the colored schedule come from SMEM;
-the roulette's prefix sums are ``common.prefix_sum`` (no ``cumsum``); the
-PWL segment sweep is a static unroll; no value-level ``dynamic_slice``.
+the roulette's prefix sums are ``common.prefix_sum``, a statically unrolled
+log-depth doubling scan (no ``cumsum``, no loop); the PWL segment sweep is a
+static unroll; no value-level ``dynamic_slice``.
 """
 from __future__ import annotations
 

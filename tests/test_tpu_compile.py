@@ -63,6 +63,7 @@ SWEEPS = {
     "dense-2000-rsa": ("dense", 2000, 8, "rsa", False),
     "dense-2000-rwa": ("dense", 2000, 8, "rwa", False),
     "dense-2000-rsa-pwl": ("dense", 2000, 8, "rsa", True),
+    "dense-2000-rwa-pwl": ("dense", 2000, 8, "rwa", True),  # k2000.*
     "dense-2000-rwa-r16": ("dense", 2000, 16, "rwa", False),
     "bitplane-8000-rsa": ("bitplane", 8000, 8, "rsa", False),
     "bitplane-7000-rwa": ("bitplane", 7000, 8, "rwa", False),
